@@ -343,4 +343,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -3,8 +3,9 @@
 ``BENCH_smoke.json`` rows and exported traces are compared across runs,
 machines, and PRs; a row without provenance is a number you cannot
 trust a week later.  :func:`provenance` returns the stamp — git sha,
-host, platform, python, wall-clock — that the benchmark harness attaches
-to every row and the tracer embeds in ``otherData``.
+host, platform, python, wall-clock, and the JAX device the process ran
+on — that the benchmark harness attaches to every row and the tracer
+embeds in ``otherData``.
 
 The git sha is resolved once per process (``git rev-parse HEAD`` from
 this file's repo, overridable via ``REPRO_GIT_SHA`` for environments
@@ -42,6 +43,20 @@ def git_sha() -> str:
     return _GIT_SHA
 
 
+def jax_device() -> Optional[dict]:
+    """``{"platform", "kind", "count"}`` of JAX's default devices, as
+    JAX reports them; ``None`` when this process never imported JAX
+    (a model-only run computed nothing on a device, and stamping it
+    must not initialize a backend)."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def provenance(extra: Optional[Mapping] = None) -> dict:
     """The provenance stamp: stable identity fields plus ``extra``
     (per-row measurements like compile wall time / pass timings)."""
@@ -50,6 +65,7 @@ def provenance(extra: Optional[Mapping] = None) -> dict:
         "host": platform.node() or "unknown",
         "platform": platform.platform(),
         "python": sys.version.split()[0],
+        "device": jax_device(),
         "time_unix": round(time.time(), 3),
     }
     if extra:

@@ -7,7 +7,7 @@ the input tensor on-chip.  The mapping:
   FPGA                              TPU (this kernel)
   ----------------------------      ---------------------------------
   hls::stream row arrivals          sequential grid steps (R rows each)
-  (K-1)×N BRAM line buffer          VMEM scratch (KH-1, Wp, Cin),
+  (K-1)×N BRAM line buffer          VMEM scratch rows (KH-s, Wp, Cin),
                                     persisted across grid steps
   K×K window regs + DSP MAC tree    (R,W,Cin)×(Cin,Cout) MXU matmuls,
                                     one per (kh, kw) tap
@@ -22,6 +22,9 @@ Grid: ``(B, Hp // rows_per_block)`` — the row-block count is chosen by
 the DSE (``repro.core.dse.plan_conv_rows``) so the VMEM working set
 (line buffer + weights + R output rows) fits the budget, the direct dual
 of the paper's BRAM constraint.
+
+Operands are float or integers of at most 8 bits
+(:func:`kernel_accepts`): Mosaic has no matmul for wider integers.
 """
 from __future__ import annotations
 
@@ -35,6 +38,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _acc_dtype(dtype) -> jnp.dtype:
     return jnp.int32 if jnp.issubdtype(dtype, jnp.integer) else jnp.float32
+
+
+class KernelDtypeError(TypeError):
+    """Operand dtypes the compiled (non-interpret) kernel cannot take."""
+
+
+def kernel_accepts(*dtypes) -> bool:
+    """Whether the TPU kernel takes operands of these dtypes: floats,
+    and integers of at most 8 bits (the MXU's int8 path).  Mosaic
+    refuses an int16/int32 matmul, so wider integers need another
+    lowering (``ops.conv2d_same_mm``)."""
+    return all(
+        not jnp.issubdtype(d, jnp.integer) or jnp.dtype(d).itemsize == 1
+        for d in dtypes
+    )
 
 
 #: fused-epilogue kinds the conv path supports — mirrors the DFG-level
@@ -68,10 +86,10 @@ def line_buffer_rows(kh: int, stride: int) -> int:
 
 
 def _conv_stream_kernel(
-    x_ref,      # (1, Rin, Wp, Cin)  current row block (the "stream")
+    x_ref,      # (1, Rin, s*Wq, Cin)  current row block (the "stream")
     w_ref,      # (KH, KW, Cin, Cout)
     o_ref,      # (1, Rin//s, W, Cout)
-    lb_ref,     # (max(KH-s,0), Wp, Cin)  the line buffer (VMEM scratch)
+    win_ref,    # (C + Rin + s - 1, s*Wq, Cin)  line buffer + block (VMEM)
     *,
     kh: int,
     kw: int,
@@ -79,32 +97,38 @@ def _conv_stream_kernel(
     stride: int,
     epilogue: str | None,
 ):
+    """One row block.  ``win_ref`` rows ``[0, C)`` are the line buffer
+    (the last ``C`` rows of the previous block), rows ``[C, C + Rin)``
+    the current block; the ``s - 1`` rows after them are never read
+    into a result.  The stride is taken without strided loads, which
+    Mosaic refuses for sub-32-bit data: columns arrive phase-split from
+    the wrapper (column ``dw + s*j`` of the frame sits at
+    ``(dw % s)*Wq + dw//s + j``), and rows are read contiguously, split
+    ``(Rout, s)`` on the untiled leading axis and indexed at phase 0."""
     i = pl.program_id(1)
     acc_t = _acc_dtype(o_ref.dtype)
     carry = line_buffer_rows(kh, stride)
+    rin = x_ref.shape[1]
+    wq = x_ref.shape[2] // stride
 
-    @pl.when(i == 0)
-    def _init():
-        lb_ref[...] = jnp.zeros_like(lb_ref)
-
-    cur = x_ref[0]                                   # (Rin, Wp, Cin)
     if carry > 0:
-        window = jnp.concatenate([lb_ref[...], cur], axis=0)  # (carry+Rin, ...)
-    else:
-        window = cur
-    r_out = cur.shape[0] // stride                   # output rows per block
+        @pl.when(i == 0)
+        def _init():
+            win_ref[:carry] = jnp.zeros(
+                (carry,) + win_ref.shape[1:], win_ref.dtype)
+
+    win_ref[carry:carry + rin] = x_ref[0]
+    r_out = rin // stride                            # output rows per block
 
     acc = jnp.zeros((r_out, w_out, o_ref.shape[-1]), acc_t)
     for dh in range(kh):
         for dw in range(kw):
-            patch = window[
-                dh : dh + (r_out - 1) * stride + 1 : stride,
-                dw : dw + (w_out - 1) * stride + 1 : stride,
-                :,
-            ]                                                  # (Rout, W, Cin)
+            col = (dw % stride) * wq + dw // stride
+            rows = win_ref[dh:dh + r_out * stride, col:col + w_out, :]
+            patch = rows.reshape(r_out, stride, w_out, rows.shape[-1])[:, 0]
             tap = w_ref[dh, dw]                                # (Cin, Cout)
             acc = acc + jax.lax.dot_general(
-                patch,
+                patch,                                         # (Rout, W, Cin)
                 tap,
                 (((2,), (0,)), ((), ())),
                 preferred_element_type=acc_t,
@@ -113,7 +137,21 @@ def _conv_stream_kernel(
     o_ref[...] = acc[None].astype(o_ref.dtype)
 
     if carry > 0:
-        lb_ref[...] = window[-carry:]
+        win_ref[:carry] = win_ref[rin:rin + carry]
+
+
+def _split_column_phases(x: jax.Array, stride: int) -> jax.Array:
+    """(B, H, Wp, C) → (B, H, s*Wq, C), ``Wq = ceil(Wp / s)``: phase
+    ``p`` (columns ``p, p+s, ...``, zero-padded to ``Wq``) occupies
+    columns ``[p*Wq, (p+1)*Wq)``, so the kernel reads every tap's
+    stride-``s`` columns as one contiguous slice.  Identity at s=1."""
+    if stride == 1:
+        return x
+    wp = x.shape[2]
+    wq = -(-wp // stride)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, wq * stride - wp), (0, 0)))
+    return jnp.concatenate([x[:, :, p::stride] for p in range(stride)],
+                           axis=2)
 
 
 def conv2d_stream_pallas(
@@ -135,19 +173,31 @@ def conv2d_stream_pallas(
     discipline at stride ``s``).  ``epilogue`` generalizes ``fuse_relu``
     to any supported fused elementwise tail (``CONV_EPILOGUES``);
     ``fuse_relu=True`` is kept as sugar for ``epilogue="relu"``.
+
+    Compiled (``interpret=False``) operands must pass
+    :func:`kernel_accepts`; others raise :class:`KernelDtypeError`.
     """
+    if not interpret and not kernel_accepts(x_padded.dtype, w.dtype):
+        raise KernelDtypeError(
+            f"conv2d_stream: operands {x_padded.dtype}/{w.dtype} — the TPU "
+            "kernel takes floats and integers of at most 8 bits; lower "
+            "wider integers with ops.conv2d_same_mm"
+        )
     if fuse_relu:
         if epilogue not in (None, "relu"):
             raise ValueError("fuse_relu=True conflicts with epilogue="
                              f"{epilogue!r}")
         epilogue = "relu"
-    b, hp, wp, cin = x_padded.shape
+    b, hp, _, cin = x_padded.shape
     kh, kw_, _, cout = w.shape
     assert hp % rows_per_block == 0, (hp, rows_per_block)
     assert rows_per_block % stride == 0, (rows_per_block, stride)
     nb = hp // rows_per_block
     rows_out = rows_per_block // stride
     acc_t = _acc_dtype(x_padded.dtype)
+    x_ph = _split_column_phases(x_padded, stride)
+    wph = x_ph.shape[2]
+    win_rows = line_buffer_rows(kh, stride) + rows_per_block + stride - 1
 
     kernel = functools.partial(
         _conv_stream_kernel, kh=kh, kw=kw_, w_out=w_out, stride=stride,
@@ -158,7 +208,7 @@ def conv2d_stream_pallas(
         grid=(b, nb),
         in_specs=[
             pl.BlockSpec(
-                (1, rows_per_block, wp, cin), lambda bb, i: (bb, i, 0, 0)
+                (1, rows_per_block, wph, cin), lambda bb, i: (bb, i, 0, 0)
             ),
             pl.BlockSpec((kh, kw_, cin, cout), lambda bb, i: (0, 0, 0, 0)),
         ],
@@ -166,8 +216,6 @@ def conv2d_stream_pallas(
             (1, rows_out, w_out, cout), lambda bb, i: (bb, i, 0, 0)
         ),
         out_shape=jax.ShapeDtypeStruct((b, hp // stride, w_out, cout), acc_t),
-        scratch_shapes=[pltpu.VMEM(
-            (max(line_buffer_rows(kh, stride), 1), wp, cin), x_padded.dtype
-        )],
+        scratch_shapes=[pltpu.VMEM((win_rows, wph, cin), x_padded.dtype)],
         interpret=interpret,
-    )(x_padded, w)
+    )(x_ph, w)

@@ -124,6 +124,8 @@ def conv2d_stream(
 
     Returns int32 accumulators for integer inputs (paper's int8 PTQ path),
     f32 otherwise — requantization is the caller's (graph's) concern.
+    Compiled (``interpret=False``) operands must be float or integers of
+    at most 8 bits; wider integers raise ``KernelDtypeError``.
 
     ``epilogue`` fuses an elementwise tail into the kernel's writeback
     (``"relu"`` | ``"squared_relu"``) — the TPU realization of the pass
@@ -183,8 +185,10 @@ def conv2d_same_mm(
 ) -> jax.Array:
     """NHWC conv as KH·KW shifted channel matmuls.
 
-    The throughput lowering the *batched* executables use for integer
-    inputs: XLA's CPU path for integer ``lax.conv`` is a naive loop, an
+    The lowering of integer convs whose operands are wider than 8 bits
+    (on every path: the TPU kernel cannot take them) and of every
+    integer conv in the *batched* executables, where it was picked by
+    CPU timing: XLA's CPU path for integer ``lax.conv`` is a naive loop, an
     order of magnitude slower than its integer dot — so the conv is
     decomposed into one ``(N·H·W, Cin) @ (Cin, Cout)`` matmul per
     kernel tap, accumulated in **int32** — the same accumulator the
@@ -297,12 +301,19 @@ def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
     lowering, but structurally the same tiled schedule the emitter
     realizes.
 
-    ``fast_int_conv`` (the batched-executable path) lowers
-    integer-dtype convs through :func:`conv2d_same_mm` instead of the
-    streaming Pallas kernel — bit-exact for integers (modular addition
-    is order-independent), and the difference between ~2× and ~8×
-    batched throughput on CPU.  Float convs ignore the flag and keep
-    the Pallas kernel so batched and per-sample runs stay bit-exact.
+    Conv lowering, chosen by operand dtype:
+
+    * an integer operand wider than 8 bits (int16/int32 activations or
+      weights — e.g. the int32 accumulators an unrequantized conv hands
+      the next one) takes :func:`conv2d_same_mm` on every path: the TPU
+      kernel has no such matmul (``conv2d_stream.kernel_accepts``);
+    * int8/uint8 and float operands take the streaming Pallas kernel,
+      except that ``fast_int_conv`` (the batched-executable path) sends
+      every integer conv to :func:`conv2d_same_mm`.
+
+    Integer lowerings are bit-exact with each other (modular addition is
+    order-independent); float convs always keep the Pallas kernel, so
+    batched and per-sample runs stay bit-exact.
     """
     if weight_tiles > 1:
         tiled = _weight_tile_axes(op, dfg)
@@ -332,21 +343,21 @@ def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
                 len(stream) == 1 and len(const) == 1
                 and op.n_dims == 7 and info.dilation == 1
             ):
-                x_in = env[stream[0]]
+                x_in, w = env[stream[0]], env[const[0]]
                 # the maps determine the reach; whatever exceeds the
                 # actual input extent is the zero-padding frame (SAME
                 # splits end-heavy, VALID reads within bounds -> (0,0))
                 pads = conv_spatial_pads(op, tuple(x_in.shape))
                 padding = (pads[1], pads[2])
-                if fast_int_conv and jnp.issubdtype(
-                    x_in.dtype, jnp.integer
+                if not _conv.kernel_accepts(x_in.dtype, w.dtype) or (
+                    fast_int_conv and jnp.issubdtype(x_in.dtype, jnp.integer)
                 ):
-                    out = conv2d_same_mm(x_in, env[const[0]],
+                    out = conv2d_same_mm(x_in, w,
                                          stride=info.stride, padding=padding)
                     return _ref.apply_epilogue(out, op.epilogue, env)
                 kern_epi, rest = _split_conv_epilogue(op)
                 out = conv2d_stream(
-                    x_in, env[const[0]],
+                    x_in, w,
                     stride=info.stride, padding=padding,
                     epilogue=kern_epi, interpret=interpret,
                 )

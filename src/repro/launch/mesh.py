@@ -8,6 +8,7 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False, tp: int = 0):
@@ -38,13 +39,20 @@ def make_production_mesh(*, multi_pod: bool = False, tp: int = 0):
         chips = shape[-1] * shape[-2]
         assert chips % tp == 0, (chips, tp)
         shape = (*shape[:-2], chips // tp, tp)
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh for tests (host platform devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with Auto axes: the sharding rules here are
+    propagated by the compiler, not carried in the array types (which
+    ``make_mesh``'s default Explicit axes would require)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))

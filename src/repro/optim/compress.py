@@ -16,8 +16,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -88,7 +87,7 @@ def compressed_psum_pod(
             mesh=mesh,
             in_specs=(P(), P()),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )
         return fn(g, err)
 

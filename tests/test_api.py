@@ -403,3 +403,27 @@ class TestCli:
                        "off", "--quiet"])
         assert rc == 1
         assert "infeasible" in capsys.readouterr().err
+
+    def test_cli_keeps_compile_cache_where_env_says(self, tmp_path, subproc):
+        """The entry point caches compiled executables in
+        ``JAX_COMPILATION_CACHE_DIR`` when it is set, and nowhere else."""
+        from repro import compile_cache
+
+        def repo_entries():
+            if not os.path.isdir(compile_cache.REPO_CACHE_DIR):
+                return set()
+            return set(os.listdir(compile_cache.REPO_CACHE_DIR))
+
+        before = repo_entries()
+        cache = tmp_path / "jax_cache"
+        r = subproc(
+            "import runpy, sys\n"
+            "sys.argv = ['repro', 'compile', 'conv_relu_32', '--run',"
+            " '--quiet']\n"
+            "runpy.run_module('repro', run_name='__main__')\n",
+            env={"JAX_COMPILATION_CACHE_DIR": str(cache)},
+        )
+        assert r.returncode == 0, r.stderr[-1500:]
+        assert "ran OK" in r.stdout
+        assert any(cache.iterdir())
+        assert repo_entries() == before

@@ -148,7 +148,9 @@ class TestStridedConvProperty:
             assert art.feasible
 
             w = rng.integers(-4, 5, (k, k, c_in, c_out)).astype(np.int8)
-            x = rng.integers(-4, 5, (1, h, h, c_in)).astype(np.int32)
+            # int8 operands: the per-sample run takes the streaming
+            # kernel (wider integers lower through conv2d_same_mm)
+            x = rng.integers(-4, 5, (1, h, h, c_in)).astype(np.int8)
             got = np.asarray(
                 art.run({"x": x}, params={"w": w}, interpret=True)
             )
@@ -205,6 +207,24 @@ class TestIntegerAccumulators:
         assert b.dtype == jnp.int32
         assert int(np.max(np.asarray(b))) > np.iinfo(np.int16).max
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    def test_wide_int_per_sample_conv_takes_xla(self):
+        """int32 operands take ``conv2d_same_mm`` on the per-sample path
+        too: with ``interpret=False`` on the CPU no Pallas kernel may be
+        reached (it cannot lower there), and the result is the
+        interpreter's."""
+        from repro.passes import interp
+
+        art = api.compile_graph(cnn_graphs.conv_relu(8, c_out=4))
+        env = interp.random_env(art.source, seed=3)
+        (x_name,) = art.source.graph_inputs
+        params = {k: v for k, v in env.items() if k != x_name}
+        got = art.run(env[x_name], params, interpret=False)
+        want = interp.graph_outputs(art.source, env)
+        (out_name,) = art.source.graph_outputs
+        assert got.dtype == np.int32
+        np.testing.assert_array_equal(np.asarray(got),
+                                      np.asarray(want[out_name]))
 
     def test_vmap_loop_bit_exact_int8_end_to_end(self):
         """An all-int8 batched run (inputs *and* weights — the PTQ

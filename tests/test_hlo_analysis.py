@@ -56,7 +56,7 @@ class TestCollectives:
         code = """
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_host_mesh
 from repro.launch.hlo_analysis import analyze_hlo
 
@@ -143,7 +143,7 @@ class TestTrafficAttribution:
         code = """
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.launch.mesh import make_host_mesh
 from repro.launch.hlo_analysis import analyze_hlo
 mesh = make_host_mesh((4,), ("data",))

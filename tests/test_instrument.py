@@ -344,6 +344,27 @@ class TestProvenance:
             assert key in p
         assert p["k"] == "v"
 
+    def test_names_the_jax_device(self):
+        import jax
+
+        devices = jax.devices()
+        assert provenance()["device"] == {
+            "platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices),
+        }
+
+    def test_no_device_without_jax(self, subproc):
+        r = subproc(
+            "import sys\n"
+            "from repro.instrument import provenance\n"
+            "assert provenance()['device'] is None\n"
+            "assert 'jax' not in sys.modules\n"
+            "print('OK')\n"
+        )
+        assert r.returncode == 0, r.stderr[-1500:]
+        assert "OK" in r.stdout
+
     def test_env_override(self, monkeypatch):
         import importlib
 

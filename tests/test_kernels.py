@@ -77,6 +77,41 @@ class TestConv2dStream:
         exp = ref.conv2d(x, w)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
 
+    @pytest.mark.parametrize("dtype", [jnp.int8, jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("stride,k,hw,rows", [
+        (2, 3, (13, 11), None), (2, 3, (12, 12), 2), (3, 5, (17, 14), 3),
+        (3, 2, (12, 9), None), (2, 1, (8, 8), 4), (4, 3, (15, 15), None),
+    ])
+    def test_strided(self, dtype, stride, k, hw, rows):
+        """Stride-s convs (phase-split columns, leading-axis row split)
+        agree with the dense oracle, for any row blocking."""
+        kx, kw = jax.random.split(jax.random.key(5))
+        x = _rand(kx, (2,) + hw + (3,), dtype)
+        w = _rand(kw, (k, k, 3, 5), dtype)
+        for padding in ("SAME", "VALID"):
+            out = ops.conv2d_stream(x, w, stride=stride, padding=padding,
+                                    rows_per_block=rows)
+            exp = ref.conv2d(x, w, stride=stride, padding=padding)
+            assert out.shape == exp.shape
+            np.testing.assert_allclose(
+                np.asarray(out, np.float32), np.asarray(exp, np.float32),
+                atol=1e-2 if dtype == jnp.bfloat16 else 1e-4, rtol=1e-2,
+            )
+
+    @pytest.mark.parametrize("dtypes", [(jnp.int32, jnp.int8),
+                                        (jnp.int8, jnp.int16)],
+                             ids=["int32_x", "int16_w"])
+    def test_wide_integers_refused_when_compiled(self, dtypes):
+        """The TPU kernel has no int16/int32 matmul: asking for the
+        compiled kernel names the dtype rule instead of failing inside
+        the TPU compiler."""
+        from repro.kernels.conv2d_stream import KernelDtypeError
+
+        x = jnp.ones((1, 8, 8, 4), dtypes[0])
+        w = jnp.ones((3, 3, 4, 8), dtypes[1])
+        with pytest.raises(KernelDtypeError, match="at most 8 bits"):
+            ops.conv2d_stream(x, w, interpret=False)
+
 
 # ---------------------------------------------------------------------------
 # flash attention
