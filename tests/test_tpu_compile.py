@@ -33,10 +33,11 @@ def topo():
         # the TPU library otherwise writes its logs under the temp dir
         mp.setenv("TPU_LOG_DIR", os.environ.get("TPU_LOG_DIR", "disabled"))
         try:
-            yield topologies.get_topology_desc(platform="tpu",
-                                               topology_name="v5e:2x2")
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
         except Exception as e:
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
 
 
 @pytest.fixture(scope="module")
