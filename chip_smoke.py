@@ -18,15 +18,25 @@ checked):
   requests in bursts that form batches of 1, a middle size and
   ``max_batch``.  Every future resolves, nothing is rejected, every
   batch's outcome is ``ok``, every answer is bit-exact.
-* ``per_sample_lenet5``: one unbatched ``art.run``; its group lowers
-  through the Pallas streaming kernel (``tpu_custom_call``), bit-exact.
+* ``per_sample_lenet5``: one unbatched ``art.run``, bit-exact; the
+  executable it ran holds the Pallas streaming kernel
+  (``tpu_custom_call``) for conv 1, which is fed int8.  Conv 2 is fed
+  conv 1's int32 accumulators and lowers through XLA.
 * ``resnet_tiny``: the strided ONNX golden (stride 2, folded
-  BatchNorm, global average pool), served and per sample, bit-exact;
-  the per-sample stride-2 conv runs in the Pallas kernel.
+  BatchNorm, global average pool), served and per sample, bit-exact.
+  Only its per-sample stem conv, fed int8, runs in the Pallas kernel:
+  convs 2 and 3 take int32 accumulators, and the served path lowers
+  every integer conv through XLA.
 * ``deep_cascade_224``: 4 (Conv3×3 + ReLU) layers of 136 channels at
   224², partitioned into 4 groups for KV260, float32, per sample and
-  batched at 4.  Groups lower to Pallas kernels; outputs agree with
-  the reference within ``F32_REL_BOUND``.
+  batched at 4.  The executable of every group holds a Pallas kernel;
+  outputs agree with the reference within ``F32_REL_BOUND``.
+* ``resnet18_strided``: the four stride-2 convs of ResNet-18 at their
+  published widths (7×7 stem at 224²×3→64, then 3×3 at 56²×64→128,
+  28²×128→256, 14²×256→512), each + ReLU, a 2×2 max pool after the
+  stem; float32, per sample and batched at 4, checked like
+  ``deep_cascade_224``.  Every strided conv here runs in the Pallas
+  kernel, the last one weight-streamed in 4 tiles.
 
 Any failure raises and exits non-zero.  When every phase passed, the
 last line is ``{"ok": true, "device": {"platform": "tpu", "kind": ...,
@@ -91,23 +101,29 @@ def reference(dfg, params, xs):
         return [np.asarray(fn({**params, in_name: x})) for x in xs]
 
 
-def lowered_text(group, dtypes, batch=None) -> str:
-    """StableHLO of ``group``'s executable as ``art.run`` builds it (the
-    interpret choice left to the runtime), for values of ``dtypes``."""
+def executed_texts(art, params, x, batch=None) -> list[str]:
+    """StableHLO of each group's executable that ``art.run`` cached and
+    ran for ``params`` and samples like ``x``, unbatched or at
+    ``batch``.  Each group's argument types are the previous groups'
+    output types, as the run chained them."""
     import jax
 
     from repro.kernels import ops
 
-    src = group.dfg
-    lead = () if batch is None else (batch,)
-    env = {
-        k: jax.ShapeDtypeStruct(
-            (() if v.is_constant else lead) + tuple(v.shape), dtypes[k])
-        for k, v in src.values.items()
-        if v.is_constant or k in src.graph_inputs
-    }
-    fn = ops.lower_group(group, jit=False, batch=batch)
-    return jax.jit(fn).lower(env).as_text()
+    bucket = None if batch is None else ops.batch_bucket(batch)
+    lead = () if bucket is None else (bucket,)
+    (in_name,) = art.source.graph_inputs
+    env = {k: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype)
+           for k, v in params.items()}
+    env[in_name] = jax.ShapeDtypeStruct(lead + x.shape, x.dtype)
+    texts = []
+    for g in art.design.groups:
+        fn = ops.cached_executable(g, batch=bucket)
+        check(fn is not None,
+              f"{g.name}: art.run cached no executable (batch {bucket})")
+        texts.append(fn.lower(env).as_text())
+        env.update(jax.eval_shape(fn, env))
+    return texts
 
 
 def int8_inputs(shape, n, seed):
@@ -150,12 +166,6 @@ def check_exact(got, want, what):
               f"{what}[{i}]: {int(np.sum(g != w))} elements differ")
 
 
-def dtypes_of(art, params, x_dtype):
-    src = art.source
-    return {k: (params[k].dtype if v.is_constant else x_dtype)
-            for k, v in src.values.items()}
-
-
 def phase_served_lenet5(model, art, xs, want):
     from repro.serve import ServeConfig
 
@@ -171,11 +181,10 @@ def phase_served_lenet5(model, art, xs, want):
 
 
 def phase_per_sample(name, model, art, x, want):
-    (group,) = art.design.groups
-    text = lowered_text(group, dtypes_of(art, model.params, x.dtype))
+    check_exact([art.run(x, model.params)], [want], name)
+    (text,) = executed_texts(art, model.params, x)
     check("tpu_custom_call" in text,
           f"{name}: no Pallas TPU kernel in the per-sample executable")
-    check_exact([art.run(x, model.params)], [want], name)
     return "unbatched art.run through the Pallas kernel, bit-exact"
 
 
@@ -199,14 +208,29 @@ def rel_errors(got, want):
     return l2, mx
 
 
-def deep_cascade_setup(n_size=224, batch=4):
-    """(artifact, float32 params, inputs of batch ``batch``, CPU
-    reference per sample) for ``deep_cascade(n_size)`` on KV260."""
-    from repro import CompileOptions, compile_graph
-    from repro.core import cnn_graphs
+def resnet18_strided(n_size=224):
+    """ResNet-18's stride-2 convs (He et al. 2016, Table 1) at their
+    published widths, chained: the 7×7/2 stem (+ReLU), a 2×2/2 max pool
+    in place of the stem's padded 3×3/2 one, and the first 3×3/2 conv
+    of conv3_x, conv4_x and conv5_x (+ReLU).  The stride-1 convs and
+    the shortcuts are cut."""
+    from repro.api import Conv2D, MaxPool, ReLU, Sequential
 
-    art = compile_graph(cnn_graphs.deep_cascade(n_size),
-                        CompileOptions(target="kv260"))
+    return Sequential(
+        [Conv2D(64, kernel=7, stride=2), ReLU(), MaxPool(2),
+         Conv2D(128, stride=2), ReLU(), Conv2D(256, stride=2), ReLU(),
+         Conv2D(512, stride=2), ReLU()],
+        input_shape=(1, n_size, n_size, 3),
+        name=f"resnet18_strided_{n_size}",
+    ).build()
+
+
+def float_setup(dfg, batch=4):
+    """(artifact, float32 params, inputs of batch ``batch``, CPU
+    reference per sample) for ``dfg`` on KV260."""
+    from repro import CompileOptions, compile_graph
+
+    art = compile_graph(dfg, CompileOptions(target="kv260"))
     src = art.source
     rng = np.random.default_rng(SEED)
     params = {}
@@ -221,22 +245,24 @@ def deep_cascade_setup(n_size=224, batch=4):
     return art, params, xb, reference(src, params, list(xb))
 
 
-def phase_deep_cascade(art, params, xb, want):
-    dtypes = dtypes_of(art, params, np.float32)
-    for b in (None, len(xb)):
-        texts = [lowered_text(g, dtypes, batch=b) for g in art.design.groups]
-        check(any("tpu_custom_call" in t for t in texts),
-              f"deep_cascade: no Pallas TPU kernel in any group (batch={b})")
+def phase_float(name, art, params, xb, want):
     got = [art.run(xb[0], params)] + list(art.run(xb, params))
+    for b in (None, len(xb)):
+        texts = executed_texts(art, params, xb[0], batch=b)
+        missing = [g.name for g, t in zip(art.design.groups, texts)
+                   if "tpu_custom_call" not in t]
+        check(not missing,
+              f"{name}: no Pallas TPU kernel in {missing} (batch {b})")
     want = [want[0]] + list(want)
     check(all(np.isfinite(g).all() for g in got), "non-finite output")
     errs = [rel_errors(g, w) for g, w in zip(got, want)]
     l2 = max(e[0] for e in errs)
     mx = max(e[1] for e in errs)
     check(l2 <= F32_REL_BOUND and mx <= F32_REL_BOUND,
-          f"float32 error rel_l2={l2!r} max_rel={mx!r} > {F32_REL_BOUND}")
-    return (f"{len(art.design.groups)} groups with Pallas kernels, per "
-            f"sample + batch {len(xb)}; vs CPU reference "
+          f"{name}: float32 error rel_l2={l2!r} max_rel={mx!r} > "
+          f"{F32_REL_BOUND}")
+    return (f"{len(art.design.groups)} groups, each with a Pallas kernel, "
+            f"per sample + batch {len(xb)}; vs CPU reference "
             f"rel_l2={l2!r} max_rel={mx!r} (bound {F32_REL_BOUND})")
 
 
@@ -290,10 +316,14 @@ def main() -> int:
     want = reference(resnet.dfg, resnet.params, xs)
     phase("resnet_tiny", phase_resnet_tiny, resnet, resnet_art, xs, want)
 
-    deep = deep_cascade_setup()
-    check(len(deep[0].design.groups) == 4,
-          f"deep_cascade_224: {len(deep[0].design.groups)} groups, not 4")
-    phase("deep_cascade_224", phase_deep_cascade, *deep)
+    from repro.core import cnn_graphs
+
+    for name, dfg in (("deep_cascade_224", cnn_graphs.deep_cascade(224)),
+                      ("resnet18_strided", resnet18_strided())):
+        setup = float_setup(dfg)
+        check(len(setup[0].design.groups) == 4,
+              f"{name}: {len(setup[0].design.groups)} groups, not 4")
+        phase(name, phase_float, name, *setup)
     print(json.dumps({"ok": True, "device": device}))
     return 0
 

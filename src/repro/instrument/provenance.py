@@ -45,11 +45,15 @@ def git_sha() -> str:
 
 def jax_device() -> Optional[dict]:
     """``{"platform", "kind", "count"}`` of JAX's default devices, as
-    JAX reports them; ``None`` when this process never imported JAX
-    (a model-only run computed nothing on a device, and stamping it
-    must not initialize a backend)."""
+    JAX reports them; ``None`` when no JAX backend is up (a compile-only
+    run computed nothing on a device, and stamping it must not start a
+    backend: on an accelerator host that would claim the device)."""
     jax = sys.modules.get("jax")
     if jax is None:
+        return None
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
         return None
     devices = jax.devices()
     return {"platform": devices[0].platform,

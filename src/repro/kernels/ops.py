@@ -517,10 +517,19 @@ def _build_group_fn(group, interpret: bool, jit: bool,
             k: (None if dfg.values[k].is_constant else 0) for k in needed
         },)
         run = jax.vmap(run, in_axes=axes)
+
+    def pick(env):
+        return {k: v for k, v in env.items() if k in needed}
+
     if not jit:
-        return lambda env: run({k: v for k, v in env.items() if k in needed})
+        return lambda env: run(pick(env))
     jitted = jax.jit(run)
-    return lambda env: jitted({k: v for k, v in env.items() if k in needed})
+
+    def call(env):
+        return jitted(pick(env))
+
+    call.lower = lambda env: jitted.lower(pick(env))
+    return call
 
 
 def lower_group(group, *, interpret: bool | None = None, jit: bool = True,
@@ -571,6 +580,17 @@ def lower_group(group, *, interpret: bool | None = None, jit: bool = True,
                              "batch": batch})
         tracer.counter("jit_cache", stats_snapshot)
     return fn
+
+
+def cached_executable(group, *, interpret: bool | None = None,
+                      batch: int | None = None):
+    """The executable :func:`lower_group` cached for ``group`` at
+    ``batch`` (a bucket, or ``None`` for per-sample), or ``None`` if
+    none was built; LRU order and stats are left alone.  Its
+    ``lower(env)`` gives the lowering of what ran."""
+    key = _group_signature(group, _auto_interpret(interpret)) + ("batch", batch)
+    with _EXEC_CACHE_LOCK:
+        return _EXEC_CACHE.get(key)
 
 
 def run_compiled(design, env, *, interpret: bool | None = None,

@@ -21,15 +21,41 @@ def _batched_inputs(src, batch, seed=0):
     rng = np.random.default_rng(seed)
     return {
         k: rng.integers(-4, 5, size=(batch,) + src.values[k].shape,
-                        dtype=np.int32)
+                        dtype=np.int8)
         for k in src.graph_inputs
     }
 
 
-def _assert_vmap_equals_loop(art, batch, seed=0):
+def _int8_params(src, seed=0):
+    rng = np.random.default_rng(seed + 1000)
+    return {
+        k: rng.integers(-4, 5, size=v.shape, dtype=np.int8)
+        for k, v in src.values.items() if v.is_constant
+    }
+
+
+def _assert_vmap_equals_loop(art, batch, seed=0, *, streams=True):
+    """int8 inputs and weights: the loop's per-sample executables take
+    the streaming Pallas kernel for every conv fed int8 (``streams``
+    asserts it got there — convs fed the int32 accumulators of an
+    earlier one lower through ``conv2d_same_mm``), while the batched
+    executables lower every integer conv through ``conv2d_same_mm``,
+    so the comparison crosses the two lowerings."""
     x = _batched_inputs(art.source, batch, seed)
-    want = art.run(x, batch_mode="loop")
-    got = art.run(x, batch_mode="vmap")
+    params = _int8_params(art.source, seed)
+    calls = []
+    real = ops.conv2d_stream
+
+    def probe(*args, **kwargs):
+        calls.append(1)
+        return real(*args, **kwargs)
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "conv2d_stream", probe)
+        ops._EXEC_CACHE.clear()  # trace afresh so the probe sees the calls
+        want = art.run(x, params, batch_mode="loop")
+    got = art.run(x, params, batch_mode="vmap")
+    assert bool(calls) == streams, calls
     if isinstance(want, dict):
         for k in want:
             np.testing.assert_array_equal(got[k], want[k])
@@ -73,13 +99,15 @@ class TestVmapBitExact:
                                 api.CompileOptions(target=target))
         _assert_vmap_equals_loop(art, batch=3, seed=7)
 
-    @pytest.mark.parametrize("make", [
-        lambda: cnn_graphs.conv_relu(8, c_out=4),
-        lambda: cnn_graphs.residual_block(8, c=4),
-        lambda: cnn_graphs.feed_forward(batch=16, d_in=8, d_hidden=16),
+    @pytest.mark.parametrize("make, streams", [
+        (lambda: cnn_graphs.conv_relu(8, c_out=4), True),
+        (lambda: cnn_graphs.residual_block(8, c=4), True),
+        (lambda: cnn_graphs.feed_forward(batch=16, d_in=8, d_hidden=16),
+         False),
     ], ids=["conv_relu", "residual", "feed_forward"])
-    def test_builder_graphs(self, make):
-        _assert_vmap_equals_loop(api.compile_graph(make()), batch=4)
+    def test_builder_graphs(self, make, streams):
+        _assert_vmap_equals_loop(api.compile_graph(make()), batch=4,
+                                 streams=streams)
 
     def test_random_builder_graphs_property(self):
         """Property-style sweep: random little Sequential stacks (seeded
@@ -106,7 +134,7 @@ class TestVmapBitExact:
         b = g.input((1, 4, 4, 2), name="b")
         g.output(g.add(a, b))
         art = api.compile_graph(g.build())
-        _assert_vmap_equals_loop(art, batch=5)
+        _assert_vmap_equals_loop(art, batch=5, streams=False)
 
     def test_bad_mode_rejected(self):
         art = api.compile_graph(cnn_graphs.conv_relu(8, c_out=4))

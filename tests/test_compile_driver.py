@@ -401,62 +401,114 @@ class TestExecutableCache:
                          env, interpret=True)
         assert calls["n"] == n_first
 
+    def test_cached_executable_is_what_ran(self):
+        """``cached_executable`` hands back the entry a run cached, for
+        that batch only, and it lowers to the StableHLO of what ran."""
+        from repro.kernels import ops
+
+        d = compile_design(cnn_graphs.conv_relu(8, c_out=4))
+        env = interp.random_env(d.source, seed=6)
+        g = d.groups[0]
+        ops._EXEC_CACHE.clear()
+        assert ops.cached_executable(g, interpret=True) is None
+        ops.run_compiled(d, env, interpret=True)
+        fn = ops.cached_executable(g, interpret=True)
+        assert fn is ops.lower_group(g, interpret=True)
+        assert ops.cached_executable(g, interpret=True, batch=4) is None
+        # the run's int32 operands: the output type of the group
+        assert "tensor<1x8x8x4xi32>" in fn.lower(env).as_text()
+
+
+_SMALL_GRAPHS = pytest.mark.parametrize(
+    "make, convs",
+    [
+        (lambda: cnn_graphs.conv_relu(8, c_out=4), True),
+        (lambda: cnn_graphs.cascade_conv(8, c_mid=4), True),
+        (lambda: cnn_graphs.conv_pool(8, c_out=4), True),
+        (lambda: cnn_graphs.residual_block(8, c=4), True),
+        (cnn_graphs.feed_forward, False),
+    ],
+    ids=["conv_relu", "cascade", "conv_pool", "residual", "feed_forward"],
+)
+_FAT_GRAPHS = pytest.mark.parametrize(
+    "make", [cnn_graphs.fat_conv, cnn_graphs.fat_cascade],
+    ids=["fat_conv", "fat_cascade"],
+)
+
 
 class TestPallasConsumesDesign:
-    """kernels/ops.run_compiled executes the identical schedule."""
+    """kernels/ops.run_compiled executes the identical schedule.
 
-    @pytest.mark.parametrize(
-        "make",
-        [
-            lambda: cnn_graphs.conv_relu(8, c_out=4),
-            lambda: cnn_graphs.cascade_conv(8, c_mid=4),
-            lambda: cnn_graphs.conv_pool(8, c_out=4),
-            lambda: cnn_graphs.residual_block(8, c=4),
-            cnn_graphs.feed_forward,
-        ],
-        ids=["conv_relu", "cascade", "conv_pool", "residual", "feed_forward"],
-    )
-    def test_run_compiled_matches_interp(self, make):
-        dfg = make()
-        d = compile_design(dfg)
-        env = interp.random_env(d.source, seed=7)
-        want = interp.graph_outputs(d.source, env)
-        got = ops_run(d, env)
-        assert set(want) == set(got)
-        for k in want:
-            np.testing.assert_array_equal(np.asarray(want[k]), np.asarray(got[k]))
+    Operands are ``interp.random_env``'s small integers in int8 or
+    float32.  int8 reaches the streaming kernel for every conv fed
+    int8 (a conv fed int32 accumulators lowers through
+    ``conv2d_same_mm``); float32 reaches it for every conv, and its
+    sums of small integers are exact in any order."""
+
+    @_SMALL_GRAPHS
+    def test_run_compiled_matches_interp(self, make, convs):
+        calls = _assert_run_matches_interp(compile_design(make()), 7, "int8")
+        assert (calls["stream"] > 0) == convs
+
+    @_SMALL_GRAPHS
+    def test_run_compiled_matches_interp_f32(self, make, convs):
+        calls = _assert_run_matches_interp(compile_design(make()), 7,
+                                           "float32")
+        assert (calls["stream"] > 0) == convs and calls["mm"] == 0
 
     def test_partitioned_design_chains_groups(self):
         fused = run_default_pipeline(cnn_graphs.cascade_conv(16, c_mid=8)).dfg
         pp = partition_layer_groups(fused, b_total=2)
-        assert pp.partitioned
-        env = interp.random_env(fused, seed=3)
-        want = interp.graph_outputs(fused, env)
-        got = ops_run(pp, env)
-        for k in want:
-            np.testing.assert_array_equal(np.asarray(want[k]), np.asarray(got[k]))
+        assert pp.partitioned and pp.source is fused
+        calls = _assert_run_matches_interp(pp, 3, "int8")
+        assert calls["stream"] > 0
 
     @pytest.mark.parametrize("strategy", ["balanced", "greedy"])
-    @pytest.mark.parametrize(
-        "make",
-        [cnn_graphs.fat_conv, cnn_graphs.fat_cascade],
-        ids=["fat_conv", "fat_cascade"],
-    )
+    @_FAT_GRAPHS
     def test_streamed_groups_match_interp(self, make, strategy):
         """Satellite: run_compiled's weight-tiled lowering (the TPU dual
         of the emitter's wtile loop) is bit-exact with the reference
         interpreter for streamed-weight groups, both strategies."""
         d = compile_design(make(), strategy=strategy)
         assert d.weight_streamed, "expected a weight-streamed schedule"
-        env = interp.random_env(d.source, seed=5)
-        want = interp.graph_outputs(d.source, env)
-        got = ops_run(d, env)
-        assert set(want) == set(got)
-        for k in want:
-            np.testing.assert_array_equal(np.asarray(want[k]), np.asarray(got[k]))
+        calls = _assert_run_matches_interp(d, 5, "int8")
+        assert calls["stream"] >= max(d.weight_streamed.values())
+
+    @pytest.mark.parametrize("strategy", ["balanced", "greedy"])
+    @_FAT_GRAPHS
+    def test_streamed_groups_match_interp_f32(self, make, strategy):
+        """Every streamed conv, not only the first, runs the kernel
+        tile by tile."""
+        d = compile_design(make(), strategy=strategy)
+        calls = _assert_run_matches_interp(d, 5, "float32")
+        assert calls["stream"] == sum(d.weight_streamed.values())
+        assert calls["mm"] == 0
 
 
-def ops_run(design, env):
+def _assert_run_matches_interp(design, seed, dtype):
+    """``run_compiled`` (interpret mode) == the interpreter, bit for
+    bit, on ``random_env(seed)`` cast to ``dtype`` → how often the
+    lowering called each conv kernel (``stream``: the Pallas streaming
+    kernel, ``mm``: ``conv2d_same_mm``) while tracing."""
     from repro.kernels import ops
 
-    return ops.run_compiled(design, env, interpret=True)
+    env = {k: v.astype(dtype)
+           for k, v in interp.random_env(design.source, seed=seed).items()}
+    want = interp.graph_outputs(design.source, env)
+    calls = {"stream": 0, "mm": 0}
+
+    def counting(kind, fn):
+        def probe(*args, **kwargs):
+            calls[kind] += 1
+            return fn(*args, **kwargs)
+        return probe
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "conv2d_stream", counting("stream", ops.conv2d_stream))
+        mp.setattr(ops, "conv2d_same_mm", counting("mm", ops.conv2d_same_mm))
+        ops._EXEC_CACHE.clear()  # trace afresh so the probes see the calls
+        got = ops.run_compiled(design, env, interpret=True)
+    assert set(want) == set(got)
+    for k in want:
+        np.testing.assert_array_equal(np.asarray(want[k]), np.asarray(got[k]))
+    return calls

@@ -365,6 +365,22 @@ class TestProvenance:
         assert r.returncode == 0, r.stderr[-1500:]
         assert "OK" in r.stdout
 
+    def test_stamping_starts_no_backend(self, subproc):
+        """A compile-only process has imported JAX but run nothing on
+        a device: its stamp names no device and starts no backend."""
+        r = subproc(
+            "import jax\n"
+            "from jax._src import xla_bridge\n"
+            "from repro.instrument import provenance\n"
+            "assert provenance()['device'] is None\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "jax.numpy.zeros(1).block_until_ready()\n"
+            "assert provenance()['device']['count'] >= 1\n"
+            "print('OK')\n"
+        )
+        assert r.returncode == 0, r.stderr[-1500:]
+        assert "OK" in r.stdout
+
     def test_env_override(self, monkeypatch):
         import importlib
 

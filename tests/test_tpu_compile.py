@@ -132,6 +132,18 @@ def test_stride2_conv(compile_tpu, dtype):
     assert "tpu_custom_call" in hlo
 
 
+@pytest.mark.parametrize("x_shape, w_shape", [
+    ((1, 224, 224, 3), (7, 7, 3, 64)),
+    ((1, 56, 56, 64), (3, 3, 64, 128)),
+], ids=["stem_224x3", "conv3_56x64"])
+def test_resnet18_stride2_conv_f32(compile_tpu, x_shape, w_shape):
+    """ResNet-18's stride-2 convs at their published widths: the 7×7
+    stem and the first conv of conv3_x."""
+    hlo = compile_tpu(_conv(stride=2, epilogue="relu"),
+                      (x_shape, jnp.float32), (w_shape, jnp.float32))
+    assert "tpu_custom_call" in hlo
+
+
 def test_vmapped_bucket8_float_group(compile_tpu):
     """The served float path: LeNet-5's group vmapped at bucket 8."""
     group, env = _lenet5_group_env(jnp.float32, batch=8)
