@@ -3,8 +3,8 @@
 Compile a zoo classifier through the serving artifact cache, stand up a
 dynamic-batching :class:`repro.serve.ServeEngine` over it, push an
 open-loop burst of requests, and show the observability contract: the
-batch coalescing, p50/p99 latency, and the serve counters landing in
-the same Chrome trace as the compile spans.
+batch coalescing, p50/p99 latency, and the serve and runtime spans
+landing in the same Chrome trace as the artifact cache's events.
 
 Run:  PYTHONPATH=src python examples/serve_batched.py
 """
@@ -58,16 +58,16 @@ def main() -> None:
                   f"{rep.achieved_qps:.0f} qps, p50 {rep.p50_ms:.1f} ms, "
                   f"p99 {rep.p99_ms:.1f} ms, mean batch {rep.mean_batch:.1f}")
 
-    # one trace, one tracer: compile spans (had we traced the compile),
-    # vmapped run:<group> spans, and the serve counter series together
+    # one trace, one tracer: the artifact cache's events, the serve
+    # worker's ming:serve.* spans and the runner's ming:* spans together
     obj = tracer.to_chrome()
     validate_chrome_trace(obj)
     serve_events = sorted({
         e["name"] for e in obj["traceEvents"]
-        if e["name"].startswith(("serve", "artifact"))
+        if e["name"].startswith(("ming:", "artifact"))
     })
     print(f"chrome trace OK: {len(obj['traceEvents'])} events, "
-          f"serve series {serve_events}")
+          f"serve and runtime events {serve_events}")
 
 
 if __name__ == "__main__":

@@ -72,7 +72,8 @@ from repro.instrument import validate_chrome_trace
 obj = validate_chrome_trace(json.load(open(sys.argv[1])))
 names = [e["name"] for e in obj["traceEvents"]]
 assert any(n.startswith("pass:") for n in names), "no pass spans in trace"
-assert any(n.startswith("run:") for n in names), "no runtime spans in trace"
+assert {"ming:run", "ming:dispatch", "ming:sync"} <= set(names), \
+    "no runtime spans in trace"
 assert "provenance" in obj.get("otherData", {}), "trace missing provenance"
 print(f"trace OK ({len(names)} events)")
 PY

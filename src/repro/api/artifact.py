@@ -200,6 +200,26 @@ class Report:
         return lines
 
 
+def _to_host(out):
+    """NumPy copies of a call's device outputs (an array, or a dict of
+    them): the run's one device-to-host boundary, a ``ming:to_host``
+    span, timed into ``run_to_host_ms`` when a registry is ambient."""
+    import numpy as np
+
+    t0 = time.perf_counter()
+    with instrument.current().span("ming:to_host", cat="runtime"):
+        if isinstance(out, Mapping):
+            host = {k: np.asarray(v) for k, v in out.items()}
+        else:
+            host = np.asarray(out)
+    reg = instrument.metrics_current()
+    if reg.enabled:
+        reg.histogram("run_to_host_ms",
+                      "device-to-host copy of a call's outputs (ms)",
+                      ).observe((time.perf_counter() - t0) * 1e3)
+    return host
+
+
 class CompiledArtifact:
     """A compiled design plus every way to consume it."""
 
@@ -382,100 +402,95 @@ class CompiledArtifact:
                         "the original graph)"
                     )
         batch = self._batch_extent(src, inputs)
-        if batch is not None and batch_mode == "loop":
-            import jax.numpy as _jnp
-            import numpy as _np
-
-            with self._tracer_scope() as tracer:
-                t0 = time.perf_counter()
-                per_sample = []
-                per_sample_stats = []
-                for i in range(batch):
-                    with tracer.span(f"sample:{i}", cat="runtime"):
-                        t_s = time.perf_counter()
-                        per_sample.append(self.run(
-                            {k: v[i] for k, v in inputs.items()},
-                            params, interpret=interpret, jit=jit, seed=seed,
-                        ))
-                        ms = (time.perf_counter() - t_s) * 1e3
-                    tracer.counter("sample_latency_ms", {"ms": ms})
-                    if self.last_run_stats is not None:
-                        per_sample_stats.append(
-                            dict(self.last_run_stats, sample=i,
-                                 wall_ms=round(ms, 3))
-                        )
-                if per_sample_stats:
-                    self.last_run_stats = {
-                        "samples": batch,
-                        "batch_mode": "loop",
-                        "wall_ms": round((time.perf_counter() - t0) * 1e3, 3),
-                        "per_sample_ms": [s["wall_ms"]
-                                          for s in per_sample_stats],
-                        "groups": per_sample_stats[-1].get("groups", []),
-                        "exec_cache": {
-                            "hits": sum(s["exec_cache"]["hits"]
-                                        for s in per_sample_stats),
-                            "misses": sum(s["exec_cache"]["misses"]
-                                          for s in per_sample_stats),
-                        },
-                        "dma_write_bytes":
-                            per_sample_stats[-1].get("dma_write_bytes", 0),
-                        "dma_read_bytes":
-                            per_sample_stats[-1].get("dma_read_bytes", 0),
-                    }
-            # stack on device, one host conversion at the boundary
-            if len(src.graph_outputs) == 1:
-                return _np.asarray(_jnp.stack(per_sample))
-            return {
-                k: _np.asarray(_jnp.stack([o[k] for o in per_sample]))
-                for k in src.graph_outputs
+        span_args = {"graph": src.name}
+        if batch is not None:
+            span_args["batch"] = batch
+        with self._tracer_scope() as tracer, \
+                tracer.span("ming:run", cat="runtime", args=span_args) as sargs:
+            if batch is not None and batch_mode == "loop":
+                return self._run_loop(inputs, params, batch,
+                                      interpret=interpret, jit=jit, seed=seed)
+            # random-fill only when something is actually unbound — a
+            # fully parameterized call (the hot path) never pays the RNG
+            # work
+            bound = set(inputs) | set(params or ())
+            needed = set(src.graph_inputs) | {
+                n for n, v in src.values.items() if v.is_constant
             }
-        # random-fill only when something is actually unbound — a fully
-        # parameterized call (the hot path) never pays the RNG work
-        bound = set(inputs) | set(params or ())
-        needed = set(src.graph_inputs) | {
-            n for n, v in src.values.items() if v.is_constant
-        }
-        env: dict = {}
-        if needed - bound:
-            env.update(interp.random_env(src, seed=seed))
-        if params:
-            env.update(params)
-        env.update(inputs)
-        if batch is not None:  # batch_mode == "vmap"
-            import numpy as _np
-
+            env: dict = {}
+            if needed - bound:
+                env.update(interp.random_env(src, seed=seed))
+            if params:
+                env.update(params)
+            env.update(inputs)
             rstats = {}
-            with self._tracer_scope() as tracer:
-                t0 = time.perf_counter()
-                with tracer.span(f"run:{src.name}", cat="runtime") as sargs:
-                    out = ops.run_compiled_batched(
-                        self.design, env, batch,
-                        interpret=interpret, jit=jit, stats_out=rstats)
-                    sargs.update({"batch": batch,
-                                  "buckets": rstats.get("batch_buckets")})
-                ms = (time.perf_counter() - t0) * 1e3
-                tracer.counter("batch_latency_ms", {"ms": ms})
-            rstats["samples"] = batch
-            rstats["batch_mode"] = "vmap"
-            rstats["exec_cache_total"] = dict(ops.exec_cache_stats)
-            self.last_run_stats = rstats
-            # outputs stayed stacked on device; NumPy once at the boundary
-            if len(src.graph_outputs) == 1:
-                return _np.asarray(out[src.graph_outputs[0]])
-            return {k: _np.asarray(out[k]) for k in src.graph_outputs}
-        rstats = {}
-        with self._tracer_scope() as tracer:
-            with tracer.span(f"run:{src.name}", cat="runtime"):
+            if batch is None:
                 out = ops.run_compiled(self.design, env,
                                        interpret=interpret, jit=jit,
                                        stats_out=rstats)
-        rstats["samples"] = 1
-        rstats["exec_cache_total"] = dict(ops.exec_cache_stats)
-        self.last_run_stats = rstats
+                rstats["samples"] = 1
+            else:  # batch_mode == "vmap"
+                out = ops.run_compiled_batched(
+                    self.design, env, batch,
+                    interpret=interpret, jit=jit, stats_out=rstats)
+                sargs.update({"buckets": rstats.get("batch_buckets")})
+                rstats["samples"] = batch
+                rstats["batch_mode"] = "vmap"
+            rstats["exec_cache_total"] = dict(ops.exec_cache_stats)
+            self.last_run_stats = rstats
+            if batch is None:  # per-sample outputs stay on device
+                if len(src.graph_outputs) == 1:
+                    return out[src.graph_outputs[0]]
+                return out
+            # outputs stayed stacked on device; NumPy once at the boundary
+            if len(src.graph_outputs) == 1:
+                return _to_host(out[src.graph_outputs[0]])
+            return _to_host(out)
+
+    def _run_loop(self, inputs: Mapping, params, batch: int, *,
+                  interpret, jit: bool, seed: int):
+        """``batch_mode="loop"``: the batch one sample at a time through
+        the compiled schedule, stacked on device."""
+        import jax.numpy as _jnp
+
+        src = self.design.source
+        t0 = time.perf_counter()
+        per_sample = []
+        per_sample_stats = []
+        for i in range(batch):
+            t_s = time.perf_counter()
+            per_sample.append(self.run(
+                {k: v[i] for k, v in inputs.items()},
+                params, interpret=interpret, jit=jit, seed=seed,
+            ))
+            ms = (time.perf_counter() - t_s) * 1e3
+            if self.last_run_stats is not None:
+                per_sample_stats.append(
+                    dict(self.last_run_stats, sample=i, wall_ms=round(ms, 3))
+                )
+        if per_sample_stats:
+            self.last_run_stats = {
+                "samples": batch,
+                "batch_mode": "loop",
+                "wall_ms": round((time.perf_counter() - t0) * 1e3, 3),
+                "per_sample_ms": [s["wall_ms"] for s in per_sample_stats],
+                "groups": per_sample_stats[-1].get("groups", []),
+                "exec_cache": {
+                    "hits": sum(s["exec_cache"]["hits"]
+                                for s in per_sample_stats),
+                    "misses": sum(s["exec_cache"]["misses"]
+                                  for s in per_sample_stats),
+                },
+                "dma_write_bytes":
+                    per_sample_stats[-1].get("dma_write_bytes", 0),
+                "dma_read_bytes":
+                    per_sample_stats[-1].get("dma_read_bytes", 0),
+            }
+        # stack on device, one host conversion at the boundary
         if len(src.graph_outputs) == 1:
-            return out[src.graph_outputs[0]]
-        return out
+            return _to_host(_jnp.stack(per_sample))
+        return _to_host({k: _jnp.stack([o[k] for o in per_sample])
+                         for k in src.graph_outputs})
 
     @staticmethod
     def _batch_extent(src: DFG, inputs: Mapping) -> Optional[int]:

@@ -255,6 +255,9 @@ class Histogram(_Instrument):
                 f"implicit), got {bounds}"
             )
         self.buckets = bounds
+        # what the declaration passed, so that re-declaring with the
+        # same object (a module-level ladder) skips the comparison
+        self._declared_buckets = buckets
 
     def _new_child(self) -> dict:
         return {"counts": [0] * len(self.buckets), "inf": 0,
@@ -348,26 +351,31 @@ class MetricsRegistry:
 
     def _declare(self, cls, name: str, help: str,
                  label_names: tuple[str, ...], **kwargs):
-        if not name or not isinstance(name, str):
-            raise ValueError(f"metric name must be a non-empty string, "
-                             f"got {name!r}")
-        with self._lock:
-            existing = self._instruments.get(name)
-            if existing is not None:
-                if (type(existing) is not cls
-                        or existing.label_names != label_names
-                        or kwargs.get("buckets") is not None
-                        and getattr(existing, "buckets", None)
-                        != tuple(float(b) for b in kwargs["buckets"])):
-                    raise ValueError(
-                        f"metric {name!r} already declared as "
-                        f"{existing.kind} with labels "
-                        f"{existing.label_names}"
-                    )
-                return existing
-            inst = cls(self, name, help, label_names, **kwargs)
-            self._instruments[name] = inst
-            return inst
+        # instruments are never removed and a dict read is atomic, so a
+        # re-declaration (a hot path's get) takes no lock
+        existing = self._instruments.get(name)
+        if existing is None:
+            if not name or not isinstance(name, str):
+                raise ValueError(f"metric name must be a non-empty string, "
+                                 f"got {name!r}")
+            with self._lock:
+                existing = self._instruments.get(name)
+                if existing is None:
+                    inst = cls(self, name, help, label_names, **kwargs)
+                    self._instruments[name] = inst
+                    return inst
+        buckets = kwargs.get("buckets")
+        if (type(existing) is not cls
+                or existing.label_names != label_names
+                or buckets is not None
+                and buckets is not existing._declared_buckets
+                and existing.buckets != tuple(float(b) for b in buckets)):
+            raise ValueError(
+                f"metric {name!r} already declared as "
+                f"{existing.kind} with labels "
+                f"{existing.label_names}"
+            )
+        return existing
 
     def counter(self, name: str, help: str = "",
                 labels: Sequence[str] = ()) -> Counter:

@@ -14,6 +14,16 @@ equivalent, one layer the whole stack threads through:
   true no-op (shared null span, discarded args), so uninstrumented
   runs stay byte-identical in output and pay no event allocation.
 
+Spans of the :data:`PROFILED` categories (``runtime``, ``serve``)
+have a second sink: each also opens a ``jax.profiler.TraceAnnotation``,
+installed tracer or not, so it lands in the profiler's ``/host:CPU``
+plane on the device trace's clock.  Their names are stable strings
+(``ming:dispatch``); what varies (graph, group, batch, bucket) goes in
+as ``args``, which become the annotation's event stats.  With no
+profiler session an annotation costs about a microsecond and is the
+only allocation.  ``jax`` is imported on the first such span, never at
+import time.
+
 Producers never import consumers: the tracer knows nothing about the
 IR, passes, or kernels — they call ``current().span(...)`` /
 ``instant`` / ``counter`` and attach whatever args they like.  The
@@ -23,13 +33,18 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import json
 import time
 from typing import Any, Callable, Iterator, Mapping, Optional
 
 #: categories the stack emits (informative, not enforced — see DESIGN.md §6)
 CATEGORIES = ("compile", "passes", "partition", "analyze", "dse", "emit",
-              "runtime")
+              "runtime", "serve")
+
+#: categories whose spans also open a profiler annotation; compile-time
+#: categories (passes, partition, analyze, ...) stay Chrome-only
+PROFILED = ("runtime", "serve")
 
 #: Chrome trace-event phases this layer produces (and the validator's
 #: accepted superset — "B"/"E" pairs appear in externally-merged traces)
@@ -65,6 +80,28 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+@functools.cache
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation`` whose ``__enter__`` yields the
+    discard dict, as every span hands out an args sink; made on first
+    use so the module imports without ``jax``."""
+    from jax.profiler import TraceAnnotation
+
+    class Annotation(TraceAnnotation):
+        def __enter__(self) -> Mapping:
+            TraceAnnotation.__enter__(self)
+            return _DISCARD
+
+    return Annotation
+
+
+def profiler_span(name: str, args: Optional[Mapping] = None):
+    """A span on the profiler's clock alone: ``name`` with ``args`` as
+    its event stats (values: str, int, float or bool)."""
+    if args:
+        return _annotation_type()(name, **args)
+    return _annotation_type()(name)
+
 
 class NullTracer:
     """The ambient default: every call is a no-op.
@@ -78,7 +115,9 @@ class NullTracer:
     ir_snapshots = False
 
     def span(self, name: str, *, cat: str = "compile",
-             args: Optional[Mapping] = None) -> _NullSpan:
+             args: Optional[Mapping] = None):
+        if cat in PROFILED:
+            return profiler_span(name, args)
         return _NULL_SPAN
 
     def instant(self, name: str, *, cat: str = "compile",
@@ -108,7 +147,9 @@ class Tracer:
             stats = run()
             args.update(stats)
 
-    Spans nest naturally (same pid/tid, enclosing ts/dur).  ``instant``
+    Spans nest naturally (same pid/tid, enclosing ts/dur); those of a
+    :data:`PROFILED` category also open a profiler annotation with the
+    ``args`` given at entry.  ``instant``
     records a point event carrying structured args (the DP search
     statistics ride one of these); ``counter`` records a sampled value
     series (jit-cache hits, DMA bytes).
@@ -145,17 +186,19 @@ class Tracer:
     def _span_cm(self, name: str, cat: str,
                  args: Optional[Mapping]) -> Iterator[dict]:
         payload: dict = dict(args) if args else {}
-        t0 = self._clock()
-        try:
-            yield payload
-        finally:
-            t1 = self._clock()
-            self.events.append({
-                "name": name, "cat": cat, "ph": "X",
-                "ts": self._us(t0),
-                "dur": round((t1 - t0) / 1e3, 3),
-                "pid": 1, "tid": 1, "args": payload,
-            })
+        with (profiler_span(name, args) if cat in PROFILED
+              else _NULL_SPAN):
+            t0 = self._clock()
+            try:
+                yield payload
+            finally:
+                t1 = self._clock()
+                self.events.append({
+                    "name": name, "cat": cat, "ph": "X",
+                    "ts": self._us(t0),
+                    "dur": round((t1 - t0) / 1e3, 3),
+                    "pid": 1, "tid": 1, "args": payload,
+                })
 
     def span(self, name: str, *, cat: str = "compile",
              args: Optional[Mapping] = None):

@@ -218,4 +218,5 @@ def conv2d_stream_pallas(
         out_shape=jax.ShapeDtypeStruct((b, hp // stride, w_out, cout), acc_t),
         scratch_shapes=[pltpu.VMEM((win_rows, wph, cin), x_padded.dtype)],
         interpret=interpret,
+        name="ming_conv2d_stream",
     )(x_ph, w)

@@ -25,12 +25,14 @@ import collections
 import dataclasses
 import functools
 import math
+import re
 import threading
 import time
 from typing import Literal
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 import repro.instrument as instrument
 from repro.instrument import metrics as _metrics
@@ -484,6 +486,13 @@ def _group_signature(group, interpret: bool) -> tuple:
     return tuple(sig)
 
 
+def jit_name(group) -> str:
+    """The name a group's executable compiles under, from the group's
+    name (``<graph>_g<i>``): ``ming_<graph>_g<i>``, non-word characters
+    made ``_``."""
+    return "ming_" + re.sub(r"\W", "_", group.name)
+
+
 def _build_group_fn(group, interpret: bool, jit: bool,
                     batch: int | None = None):
     """The uncached lowering — separable so tests can probe compile
@@ -523,6 +532,10 @@ def _build_group_fn(group, interpret: bool, jit: bool,
 
     if not jit:
         return lambda env: run(pick(env))
+    # a deterministic name, the same in every process: the compiled
+    # module (``jit_ming_<graph>_g<i>``) and its host launch event name
+    # the group
+    run.__name__ = run.__qualname__ = jit_name(group)
     jitted = jax.jit(run)
 
     def call(env):
@@ -593,6 +606,27 @@ def cached_executable(group, *, interpret: bool | None = None,
         return _EXEC_CACHE.get(key)
 
 
+def _host_nbytes(env, names) -> int:
+    """Bytes of the host (NumPy) arrays among ``env[names]``: what the
+    device receives when they are handed over."""
+    return sum(env[k].nbytes for k in names
+               if isinstance(env.get(k), np.ndarray))
+
+
+def _constants(group) -> list:
+    return [v for v, val in group.dfg.values.items() if val.is_constant]
+
+
+def _jit_outcome(before: dict) -> str:
+    """What the exec cache did for one :func:`lower_group` call, from
+    the stats taken just before it."""
+    if exec_cache_stats["hits"] > before["hits"]:
+        return "hit"
+    if exec_cache_stats["misses"] > before["misses"]:
+        return "miss"
+    return "unjitted"
+
+
 def run_compiled(design, env, *, interpret: bool | None = None,
                  jit: bool = True, stats_out: dict | None = None) -> dict:
     """Execute a :class:`~repro.core.compile_driver.CompiledDesign` on
@@ -600,13 +634,15 @@ def run_compiled(design, env, *, interpret: bool | None = None,
     value environment (the dict entries standing in for the DRAM spill
     buffers of ``host_schedule.cpp``).  Returns the graph outputs.
 
-    ``stats_out`` (ISSUE 6): pass a dict to collect runtime counters —
-    per-group wall time + jit-cache outcome, the exec-cache hit/miss
-    delta of this call, and the modeled boundary-DMA bytes per group
-    transition.  Counter collection (also active whenever a tracer is
-    installed) blocks on each group's outputs so per-group wall times
-    measure execution, not async dispatch; the uninstrumented path is
-    untouched.
+    Each group's executable call is a ``ming:dispatch`` span (arg
+    ``group``).  ``stats_out``: pass a dict to collect
+    runtime counters — per-group wall time + jit-cache outcome, the
+    exec-cache hit/miss delta of this call, and the modeled
+    boundary-DMA bytes per group transition.  Counter collection (also
+    active whenever a tracer or a metrics registry is installed) blocks
+    on each group's outputs, in a ``ming:sync`` span beside the
+    dispatch, so per-group wall times measure execution, not async
+    dispatch; the uninstrumented path never blocks.
     """
     tracer = instrument.current()
     reg = _metrics.current()
@@ -614,34 +650,38 @@ def run_compiled(design, env, *, interpret: bool | None = None,
     env = dict(env)
     if not collect:
         for g in design.groups:
-            env.update(lower_group(g, interpret=interpret, jit=jit)(env))
+            fn = lower_group(g, interpret=interpret, jit=jit)
+            with tracer.span("ming:dispatch", cat="runtime",
+                             args={"group": g.name}):
+                env.update(fn(env))
         return {v: env[v] for v in design.source.graph_outputs}
     m_wall = reg.histogram("run_group_wall_ms",
                            "per-group execution wall time (ms)",
                            labels=("group",))
     m_dma = reg.counter("run_dma_bytes_total",
                         "modeled boundary-DMA bytes", labels=("direction",))
+    m_h2d = reg.counter("run_h2d_bytes_total",
+                        "bytes of host (NumPy) arrays handed to the device",
+                        labels=("kind",))
+    if reg.enabled:
+        m_h2d.inc(_host_nbytes(env, design.source.graph_inputs),
+                  kind="inputs")
 
     before = dict(exec_cache_stats)
     transitions = design.boundary_traffic()
     rows = []
     t_run0 = time.perf_counter()
     for idx, g in enumerate(design.groups):
-        g_before = dict(exec_cache_stats)
         t0 = time.perf_counter()
-        with tracer.span(f"run:{g.name}", cat="runtime") as sargs:
-            out = lower_group(g, interpret=interpret, jit=jit)(env)
-            out = jax.block_until_ready(out)
-            env.update(out)
-            row = {
-                "group": g.name,
-                "jit_cache": (
-                    "hit" if exec_cache_stats["hits"] > g_before["hits"]
-                    else "miss"
-                    if exec_cache_stats["misses"] > g_before["misses"]
-                    else "unjitted"
-                ),
-            }
+        g_before = dict(exec_cache_stats)
+        fn = lower_group(g, interpret=interpret, jit=jit)
+        row = {"group": g.name, "jit_cache": _jit_outcome(g_before)}
+        with tracer.span("ming:dispatch", cat="runtime",
+                         args={"group": g.name}) as sargs:
+            if reg.enabled:
+                m_h2d.inc(_host_nbytes(env, _constants(g)),
+                          kind="constants")
+            out = fn(env)
             if idx < len(transitions):
                 w, r = transitions[idx]
                 row["dma_write_bytes"] = w
@@ -651,6 +691,8 @@ def run_compiled(design, env, *, interpret: bool | None = None,
                     m_dma.inc(w, direction="write")
                     m_dma.inc(r, direction="read")
             sargs.update(row)
+        with tracer.span("ming:sync", cat="runtime", args={"group": g.name}):
+            env.update(jax.block_until_ready(out))
         row["wall_ms"] = round((time.perf_counter() - t0) * 1e3, 3)
         if reg.enabled:
             m_wall.observe(row["wall_ms"], group=g.name)
@@ -669,6 +711,14 @@ def run_compiled(design, env, *, interpret: bool | None = None,
     return {v: env[v] for v in design.source.graph_outputs}
 
 
+def _pad_rows(v, bucket: int):
+    """``v`` with zero rows appended up to ``bucket`` rows."""
+    n = v.shape[0]
+    if bucket == n:
+        return v
+    return jnp.pad(v, ((0, bucket - n),) + ((0, 0),) * (v.ndim - 1))
+
+
 def run_compiled_batched(design, env, batch: int, *,
                          interpret: bool | None = None, jit: bool = True,
                          stats_out: dict | None = None) -> dict:
@@ -683,6 +733,13 @@ def run_compiled_batched(design, env, batch: int, *,
     so each group compiles at most once per bucket.  Returns the graph
     outputs as *device* arrays with a leading batch axis — the host
     conversion happens once at the caller's boundary, never per sample.
+
+    Spans: ``ming:inputs`` (the streamed inputs to the device, padded
+    to their buckets), then per chunk and group ``ming:dispatch`` and,
+    where the runner blocks (``stats_out``, a tracer or a registry, as
+    in :func:`run_compiled`), ``ming:sync``.  With a registry ambient
+    it counts ``run_h2d_bytes_total{kind=inputs|constants}`` and
+    ``run_rows_total{kind=useful|padded}``.
 
     ``interpret=False`` is the explicit device-dispatch path (real
     Pallas kernels on an accelerator); the default auto-selects
@@ -699,50 +756,63 @@ def run_compiled_batched(design, env, batch: int, *,
         m_dma = reg.counter("run_dma_bytes_total",
                             "modeled boundary-DMA bytes",
                             labels=("direction",))
+        m_h2d = reg.counter(
+            "run_h2d_bytes_total",
+            "bytes of host (NumPy) arrays handed to the device",
+            labels=("kind",))
+        m_rows = reg.counter("run_rows_total",
+                             "batch rows executed, real or bucket padding",
+                             labels=("kind",))
     src = design.source
     stream = [k for k in env
               if k in src.values and not src.values[k].is_constant]
     const_env = {k: v for k, v in env.items() if k not in stream}
 
+    with tracer.span("ming:inputs", cat="runtime", args={"batch": batch}):
+        if reg.enabled:
+            m_h2d.inc(_host_nbytes(env, stream), kind="inputs")
+        on_device = {k: jnp.asarray(env[k]) for k in stream}
+        chunks = [
+            (n, bucket, {k: _pad_rows(v[start:start + n], bucket)
+                         for k, v in on_device.items()})
+            for start, n, bucket in _batch_chunks(batch)
+        ]
+
     before = dict(exec_cache_stats)
     transitions = design.boundary_traffic()
     group_rows: dict[str, dict] = {}
-    buckets: list[int] = []
     t_run0 = time.perf_counter()
     chunks_out: list[dict] = []
-    for start, n, bucket in _batch_chunks(batch):
-        buckets.append(bucket)
-        chunk_env = dict(const_env)
-        for k in stream:
-            v = jnp.asarray(env[k])[start:start + n]
-            if bucket != n:
-                chunk_env[k] = jnp.pad(
-                    v, ((0, bucket - n),) + ((0, 0),) * (v.ndim - 1)
-                )
-            else:
-                chunk_env[k] = v
+    for n, bucket, inputs in chunks:
+        if reg.enabled:
+            m_rows.inc(n, kind="useful")
+            if bucket > n:
+                m_rows.inc(bucket - n, kind="padded")
+        chunk_env = {**const_env, **inputs}
         for idx, g in enumerate(design.groups):
-            fn = lower_group(g, interpret=interpret, jit=jit, batch=bucket)
             if not collect:
-                chunk_env.update(fn(chunk_env))
+                fn = lower_group(g, interpret=interpret, jit=jit,
+                                 batch=bucket)
+                with tracer.span("ming:dispatch", cat="runtime",
+                                 args={"group": g.name, "bucket": bucket}):
+                    chunk_env.update(fn(chunk_env))
                 continue
-            g_before = dict(exec_cache_stats)
             t0 = time.perf_counter()
-            with tracer.span(f"run:{g.name}", cat="runtime") as sargs:
-                out = jax.block_until_ready(fn(chunk_env))
-                chunk_env.update(out)
-                row = group_rows.setdefault(
-                    g.name, {"group": g.name, "wall_ms": 0.0, "samples": 0}
-                )
-                row["samples"] += n
-                row["jit_cache"] = (
-                    "hit" if exec_cache_stats["hits"] > g_before["hits"]
-                    else "miss"
-                    if exec_cache_stats["misses"] > g_before["misses"]
-                    else "unjitted"
-                )
-                sargs.update({"group": g.name, "batch": n, "bucket": bucket,
-                              "jit_cache": row["jit_cache"]})
+            g_before = dict(exec_cache_stats)
+            fn = lower_group(g, interpret=interpret, jit=jit, batch=bucket)
+            row = group_rows.setdefault(
+                g.name, {"group": g.name, "wall_ms": 0.0, "samples": 0}
+            )
+            row["samples"] += n
+            row["jit_cache"] = _jit_outcome(g_before)
+            with tracer.span("ming:dispatch", cat="runtime",
+                             args={"group": g.name, "bucket": bucket}) \
+                    as sargs:
+                if reg.enabled:
+                    m_h2d.inc(_host_nbytes(chunk_env, _constants(g)),
+                              kind="constants")
+                out = fn(chunk_env)
+                sargs.update({"batch": n, "jit_cache": row["jit_cache"]})
                 if idx < len(transitions):
                     w, r = transitions[idx]
                     sargs.update({"dma_write_bytes": w * n,
@@ -752,6 +822,9 @@ def run_compiled_batched(design, env, batch: int, *,
                     if reg.enabled:
                         m_dma.inc(w * n, direction="write")
                         m_dma.inc(r * n, direction="read")
+            with tracer.span("ming:sync", cat="runtime",
+                             args={"group": g.name}):
+                chunk_env.update(jax.block_until_ready(out))
             step_ms = (time.perf_counter() - t0) * 1e3
             if reg.enabled:
                 m_wall.observe(step_ms, group=g.name)
@@ -775,7 +848,7 @@ def run_compiled_batched(design, env, batch: int, *,
                 "hits": exec_cache_stats["hits"] - before["hits"],
                 "misses": exec_cache_stats["misses"] - before["misses"],
             },
-            "batch_buckets": buckets,
+            "batch_buckets": [bucket for _, bucket, _ in chunks],
             "dma_write_bytes": sum(w for w, _ in transitions) * batch,
             "dma_read_bytes": sum(r for _, r in transitions) * batch,
         })

@@ -13,10 +13,13 @@ Venieris' toolflow survey) on top of our bucketed jit cache: batch
 sizes land on :data:`repro.kernels.ops.BATCH_BUCKETS`, so steady-state
 traffic never recompiles.
 
-Observability is two-layered.  The PR 6 tracer still gets its post-hoc
-series (``serve_batch`` / ``serve_latency_ms`` / ``serve_qps`` plus a
-``serve:batch`` span per dispatch, in the *same* trace as the compile
-spans).  Live aggregates go to a
+Observability is two-layered.  The worker's host path is three spans
+per batch — ``ming:serve.form`` (dequeue until the batch is sealed),
+``ming:serve.stack`` (``np.stack`` of the batch), ``ming:serve.respond``
+(the futures' fan-out) — around the runner's own (``ming:run`` and
+below); like every ``serve`` and ``runtime`` span they land in the
+profiler trace, and in the tracer's Chrome trace when one is installed
+(the *same* trace as the compile spans).  Live aggregates go to a
 :class:`repro.instrument.MetricsRegistry`: every request carries an id
 and moves through four lifecycle stages — **queue-wait** (submit →
 worker dequeue), **batch-form** (dequeue → batch sealed), **execute**
@@ -28,7 +31,10 @@ post-mortems (:meth:`ServeEngine.flight_records`).  Pass
 ``registry=NULL_REGISTRY`` to switch all of it off; outputs are
 byte-identical either way (pinned by ``tests/test_metrics.py``).
 Contextvars do not cross threads, so the worker re-installs the
-engine's tracer explicitly (:func:`repro.instrument.use_tracer`).
+engine's tracer and registry explicitly
+(:func:`repro.instrument.use_tracer`, :func:`~repro.instrument.use_metrics`):
+the runner's series (``run_h2d_bytes_total``, ``run_rows_total``,
+``run_to_host_ms``, ...) land in :meth:`ServeEngine.metrics` too.
 """
 from __future__ import annotations
 
@@ -135,7 +141,6 @@ class ServeEngine:
         self._flight: "collections.deque" = collections.deque(
             maxlen=self.config.flight_records or None
         )
-        self._t_start: Optional[float] = None
         self._declare_metrics()
 
     def _declare_metrics(self) -> None:
@@ -190,7 +195,6 @@ class ServeEngine:
             env = interp.random_env(src, seed=self.seed)
             resolved.update({n: env[n] for n in missing})
         self._params_resolved = resolved
-        self._t_start = time.perf_counter()
         self._stopping = False
         self._worker = threading.Thread(
             target=self._serve_loop, name="repro-serve", daemon=True
@@ -332,34 +336,41 @@ class ServeEngine:
     # -- worker --------------------------------------------------------------
 
     def _serve_loop(self) -> None:
-        with instrument.use_tracer(self._tracer):
+        with instrument.use_tracer(self._tracer), \
+                metrics_mod.use_metrics(self.registry):
             tracer = instrument.current()
             while True:
                 item = self._queue.get()
                 if item is _STOP:
                     return
                 t_dequeue = time.perf_counter()
-                batch = [item]
-                deadline = t_dequeue + self.config.latency_budget_ms / 1e3
-                while len(batch) < self.config.max_batch:
-                    wait = deadline - time.perf_counter()
+                batch, stop = self._form(item, tracer, t_dequeue)
+                self._execute(batch, tracer, t_dequeue)
+                if stop:
+                    return
+
+    def _form(self, first, tracer, t_dequeue: float):
+        """The batch that ``first`` opens: whatever queues behind it
+        until ``max_batch`` or the latency budget; and whether the stop
+        signal came in the meantime."""
+        batch = [first]
+        deadline = t_dequeue + self.config.latency_budget_ms / 1e3
+        with tracer.span("ming:serve.form", cat="serve"):
+            while len(batch) < self.config.max_batch:
+                wait = deadline - time.perf_counter()
+                try:
                     if wait <= 0:
                         # budget spent: take whatever already queued,
                         # but don't wait for more
-                        try:
-                            nxt = self._queue.get_nowait()
-                        except queue.Empty:
-                            break
+                        nxt = self._queue.get_nowait()
                     else:
-                        try:
-                            nxt = self._queue.get(timeout=wait)
-                        except queue.Empty:
-                            break
-                    if nxt is _STOP:
-                        self._execute(batch, tracer, t_dequeue)
-                        return
-                    batch.append(nxt)
-                self._execute(batch, tracer, t_dequeue)
+                        nxt = self._queue.get(timeout=wait)
+                except queue.Empty:
+                    break
+                if nxt is _STOP:
+                    return batch, True
+                batch.append(nxt)
+        return batch, False
 
     def _execute(self, batch: list, tracer, t_dequeue: float) -> None:
         src = self.artifact.source
@@ -372,16 +383,15 @@ class ServeEngine:
             self._m_occupancy.observe(n)
         outcome = "ok"
         try:
-            stacked = {
-                k: np.stack([r.inputs[k] for r in batch])
-                for k in src.graph_inputs
-            }
-            with tracer.span("serve:batch", cat="serve",
-                             args={"batch": n}):
-                out = self.artifact.run(
-                    stacked, self._params_resolved,
-                    interpret=self.interpret, seed=self.seed,
-                )
+            with tracer.span("ming:serve.stack", cat="serve"):
+                stacked = {
+                    k: np.stack([r.inputs[k] for r in batch])
+                    for k in src.graph_inputs
+                }
+            out = self.artifact.run(
+                stacked, self._params_resolved,
+                interpret=self.interpret, seed=self.seed,
+            )
             if len(src.graph_outputs) == 1:
                 rows = [out[i] for i in range(n)]
             else:
@@ -391,8 +401,8 @@ class ServeEngine:
             t_exec_end = time.perf_counter()
             for r in batch:
                 r.future.set_exception(exc)
-            self._finish_batch(batch, tracer, t_dequeue, t_sealed,
-                               t_exec_end, time.perf_counter(), outcome)
+            self._finish_batch(batch, t_dequeue, t_sealed, t_exec_end,
+                               time.perf_counter(), outcome)
             return
         t_exec_end = time.perf_counter()
         self._bump("requests", n)
@@ -400,26 +410,15 @@ class ServeEngine:
         with self._stats_lock:
             self._stats["max_batch_seen"] = max(
                 self._stats["max_batch_seen"], n)
-        for r in batch:
-            r.future.set_result(rows.pop(0))
+        with tracer.span("ming:serve.respond", cat="serve"):
+            for r, row in zip(batch, rows):
+                r.future.set_result(row)
         t_respond = time.perf_counter()
-        if tracer.enabled:
-            tracer.counter("serve_batch", {"size": n})
-            for r in batch:
-                tracer.counter(
-                    "serve_latency_ms",
-                    {"ms": (t_exec_end - r.t_submit) * 1e3}
-                )
-            elapsed = t_exec_end - (self._t_start or t_exec_end)
-            if elapsed > 0:
-                with self._stats_lock:
-                    served = self._stats["requests"]
-                tracer.counter("serve_qps", {"qps": served / elapsed})
-        self._finish_batch(batch, tracer, t_dequeue, t_sealed,
-                           t_exec_end, t_respond, outcome)
+        self._finish_batch(batch, t_dequeue, t_sealed, t_exec_end,
+                           t_respond, outcome)
 
-    def _finish_batch(self, batch, tracer, t_dequeue, t_sealed,
-                      t_exec_end, t_respond, outcome: str) -> None:
+    def _finish_batch(self, batch, t_dequeue, t_sealed, t_exec_end,
+                      t_respond, outcome: str) -> None:
         """Record lifecycle metrics + one flight record for a finished
         (served or failed) batch."""
         reg = self.registry

@@ -265,12 +265,14 @@ class TestRuntimeCounters:
     def test_runtime_spans_and_jit_cache_events(self, ran):
         art, _ = ran
         ev = art.tracer.to_chrome()["traceEvents"]
-        runs = [e for e in ev if e["name"].startswith("run:")]
-        assert runs, "no runtime spans"
-        group_spans = [e for e in runs
-                       if any(e["name"] == f"run:{g.name}"
-                              for g in art.design.groups)]
-        assert len(group_spans) == len(art.design.groups)
+        runs = [e for e in ev if e["name"] == "ming:run"]
+        assert [e["args"]["graph"] for e in runs] == \
+            [art.design.source.name], "no runtime spans"
+        names = [g.name for g in art.design.groups]
+        for span in ("ming:dispatch", "ming:sync"):
+            group_spans = [e for e in ev if e["name"] == span]
+            assert [e["args"]["group"] for e in group_spans] == names
+            assert all(e["cat"] == "runtime" for e in group_spans)
         assert any(e["name"] == "jit_cache" for e in ev)
 
     def test_exec_cache_stats_surface_in_run_stats(self, ran):

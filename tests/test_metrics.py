@@ -90,6 +90,16 @@ class TestCounter:
         with pytest.raises(ValueError, match="already declared"):
             r.gauge("n")  # different kind
 
+    def test_redeclare_histogram_buckets(self):
+        r = MetricsRegistry()
+        h = r.histogram("lat")
+        assert r.histogram("lat") is h  # the default ladder, same object
+        assert r.histogram("lat", buckets=list(LATENCY_BUCKETS_MS)) is h
+        with pytest.raises(ValueError, match="already declared"):
+            r.histogram("lat", buckets=(1.0, 10.0))  # different bounds
+        with pytest.raises(ValueError, match="already declared"):
+            r.counter("lat")  # different kind
+
 
 class TestGauge:
     def test_set_inc_dec(self):

@@ -276,8 +276,9 @@ class TestServeTracing:
         obj = tracer.to_chrome()
         validate_chrome_trace(obj)
         names = {e["name"] for e in obj["traceEvents"]}
-        assert {"serve:batch", "serve_batch", "serve_latency_ms",
-                "serve_qps", "artifact_cache"} <= names
+        assert {"ming:serve.form", "ming:serve.stack", "ming:run",
+                "ming:serve.respond", "artifact_cache"} <= names
+        assert not {"serve_batch", "serve_latency_ms", "serve_qps"} & names
         # counter args are numeric (validate_chrome_trace-compatible)
         for ev in obj["traceEvents"]:
             if ev["ph"] == "C":
@@ -295,7 +296,7 @@ class TestServeTracing:
             for f in futs:
                 f.result(timeout=60)
         names = {e["name"] for e in art.tracer.events}
-        assert "serve:batch" in names and "serve_qps" in names
+        assert "ming:serve.stack" in names and "ming:dispatch" in names
 
 
 class TestLoadGenerator:
