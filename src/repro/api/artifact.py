@@ -15,9 +15,11 @@ or an open :class:`~repro.api.builder.Graph`) plus one
 * ``save()`` / ``load()``— persist the compiled design (the benchmark
                            cache uses this to skip recompiles).
 
-The artifact holds plain schedule-IR state only (no jitted functions,
-no arrays), so ``save``/``load`` is a straight pickle and a loaded
-artifact re-lowers through the same executable cache as a fresh one.
+The design holds plain schedule-IR state only (no jitted functions,
+no arrays), so ``save``/``load`` is a straight pickle of it and a
+loaded artifact re-lowers through the same executable cache as a
+fresh one.  The artifact beside it keeps the device copies of the
+constants ``run`` was handed, which are never saved.
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ import contextlib
 import math
 import os
 import pickle
+import threading
 import time
 from dataclasses import dataclass, field
 from typing import Mapping, Optional
@@ -229,6 +232,19 @@ class CompiledArtifact:
         #: wall time, per-group latency + jit-cache outcome, exec-cache
         #: hit/miss delta, boundary-DMA bytes; ``None`` until a run
         self.last_run_stats: Optional[dict] = None
+        #: device copies of the read-only constants :meth:`run` was
+        #: handed (``ops.ResidentConstants``), made on the first run;
+        #: the artifact's, never the pickled design's
+        self._resident = None
+        self._resident_lock = threading.Lock()
+
+    def _resident_constants(self):
+        from repro.kernels import ops
+
+        with self._resident_lock:
+            if self._resident is None:
+                self._resident = ops.ResidentConstants()
+            return self._resident
 
     @contextlib.contextmanager
     def _tracer_scope(self):
@@ -353,6 +369,15 @@ class CompiledArtifact:
         Both modes produce bit-identical stacked outputs.  All inputs
         must agree on the batch extent; mixing batched and unbatched
         inputs is an error.
+
+        **Constants** stay on the device across calls: a read-only
+        array (``np.asarray`` of a ``jax.Array`` is one) is uploaded
+        the first time it is bound and reused while the same object is
+        bound again; a writeable one is uploaded for each call, so
+        changing it in place between calls changes the answers.  Either
+        way every executable receives device arrays of one form, so a
+        warmed-up shape never compiles again
+        (:class:`repro.kernels.ops.ResidentConstants`).
         """
         from repro.kernels import ops
         from repro.passes import interp
@@ -424,15 +449,16 @@ class CompiledArtifact:
                 env.update(params)
             env.update(inputs)
             rstats = {}
+            resident = self._resident_constants()
             if batch is None:
                 out = ops.run_compiled(self.design, env,
                                        interpret=interpret, jit=jit,
-                                       stats_out=rstats)
+                                       stats_out=rstats, resident=resident)
                 rstats["samples"] = 1
             else:  # batch_mode == "vmap"
                 out = ops.run_compiled_batched(
-                    self.design, env, batch,
-                    interpret=interpret, jit=jit, stats_out=rstats)
+                    self.design, env, batch, interpret=interpret, jit=jit,
+                    stats_out=rstats, resident=resident)
                 sargs.update({"buckets": rstats.get("batch_buckets")})
                 rstats["samples"] = batch
                 rstats["batch_mode"] = "vmap"

@@ -613,8 +613,91 @@ def _host_nbytes(env, names) -> int:
                if isinstance(env.get(k), np.ndarray))
 
 
-def _constants(group) -> list:
-    return [v for v, val in group.dfg.values.items() if val.is_constant]
+def _frozen(v) -> bool:
+    """Whether ``v`` cannot change while a device copy of it is kept: a
+    ``jax.Array``, or a read-only NumPy array whose every base is
+    read-only too (``np.asarray(jax.Array)`` gives one)."""
+    if isinstance(v, jax.Array):
+        return True
+    if not isinstance(v, np.ndarray):
+        return False
+    while isinstance(v, np.ndarray):
+        if v.flags.writeable:
+            return False
+        v = v.base
+    if v is None:
+        return True
+    try:  # a buffer under the array: bytes, a memoryview, a bytearray
+        return memoryview(v).readonly
+    except TypeError:  # no buffer protocol: its producer owns the memory
+        return True
+
+
+class ResidentConstants:
+    """Device copies of one design's constants, kept across runs.
+
+    One entry per constant name: the array a copy was made from (held,
+    so its identity cannot be reused) and the copy.  A frozen array
+    (:func:`_frozen`) that *is* the entry's reuses the copy (``hit``);
+    another frozen array is uploaded once and replaces the entry
+    (``upload``), so a name never holds two copies; anything that can
+    still change is uploaded for its run alone (``bypass``).
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._entries: dict[str, tuple[object, jax.Array]] = {}
+
+    def __len__(self) -> int:
+        return len(self._entries)
+
+    def place(self, name: str, v) -> tuple[jax.Array, str]:
+        """``v``'s device copy and the outcome that gave it."""
+        if not _frozen(v):
+            return jnp.asarray(v), "bypass"
+        entry = self._entries.get(name)
+        if entry is not None and entry[0] is v:
+            return entry[1], "hit"
+        dev = jnp.asarray(v)
+        with self._lock:
+            self._entries[name] = (v, dev)
+        return dev, "upload"
+
+
+def _place_constants(design, env, resident, reg) -> tuple[dict, dict]:
+    """``env`` with every constant of the design as an uncommitted device
+    array (``jnp.asarray``, as the streamed inputs are placed), so each
+    executable meets its constants in one form whatever the caller
+    bound, and the count of each outcome of ``resident``
+    (:class:`ResidentConstants`; every constant a ``bypass`` without
+    one).  Counted into ``reg``: ``run_const_resident_total{outcome}``,
+    and the host bytes of the uploads and bypasses in
+    ``run_h2d_bytes_total{kind=constants}``."""
+    src = design.source
+    placed = dict(env)
+    outcomes = {"hit": 0, "upload": 0, "bypass": 0}
+    moved = 0
+    for k, v in env.items():
+        if k not in src.values or not src.values[k].is_constant:
+            continue
+        if resident is None:
+            placed[k], outcome = jnp.asarray(v), "bypass"
+        else:
+            placed[k], outcome = resident.place(k, v)
+        outcomes[outcome] += 1
+        if outcome != "hit" and isinstance(v, np.ndarray):
+            moved += v.nbytes
+    if reg.enabled:
+        m_res = reg.counter("run_const_resident_total",
+                            "constants handed to a run, by residency outcome",
+                            labels=("outcome",))
+        for outcome, n in outcomes.items():
+            if n:
+                m_res.inc(n, outcome=outcome)
+        reg.counter("run_h2d_bytes_total",
+                    "bytes of host (NumPy) arrays handed to the device",
+                    labels=("kind",)).inc(moved, kind="constants")
+    return placed, outcomes
 
 
 def _jit_outcome(before: dict) -> str:
@@ -628,14 +711,18 @@ def _jit_outcome(before: dict) -> str:
 
 
 def run_compiled(design, env, *, interpret: bool | None = None,
-                 jit: bool = True, stats_out: dict | None = None) -> dict:
+                 jit: bool = True, stats_out: dict | None = None,
+                 resident: ResidentConstants | None = None) -> dict:
     """Execute a :class:`~repro.core.compile_driver.CompiledDesign` on
     the Pallas path: groups run in schedule order, chained through the
     value environment (the dict entries standing in for the DRAM spill
     buffers of ``host_schedule.cpp``).  Returns the graph outputs.
 
-    Each group's executable call is a ``ming:dispatch`` span (arg
-    ``group``).  ``stats_out``: pass a dict to collect
+    The design's constants in ``env`` reach the device first, in a
+    ``ming:inputs`` span, through ``resident`` (:func:`_place_constants`).
+    Each group's executable call is a ``ming:dispatch`` span (args
+    ``group`` and the run's ``const_hit``/``const_upload`` counts).
+    ``stats_out``: pass a dict to collect
     runtime counters — per-group wall time + jit-cache outcome, the
     exec-cache hit/miss delta of this call, and the modeled
     boundary-DMA bytes per group transition.  Counter collection (also
@@ -647,12 +734,15 @@ def run_compiled(design, env, *, interpret: bool | None = None,
     tracer = instrument.current()
     reg = _metrics.current()
     collect = stats_out is not None or tracer.enabled or reg.enabled
-    env = dict(env)
+    with tracer.span("ming:inputs", cat="runtime"):
+        env, placed = _place_constants(design, env, resident, reg)
+    const_args = {"const_hit": placed["hit"],
+                  "const_upload": placed["upload"]}
     if not collect:
         for g in design.groups:
             fn = lower_group(g, interpret=interpret, jit=jit)
             with tracer.span("ming:dispatch", cat="runtime",
-                             args={"group": g.name}):
+                             args={"group": g.name, **const_args}):
                 env.update(fn(env))
         return {v: env[v] for v in design.source.graph_outputs}
     m_wall = reg.histogram("run_group_wall_ms",
@@ -677,10 +767,7 @@ def run_compiled(design, env, *, interpret: bool | None = None,
         fn = lower_group(g, interpret=interpret, jit=jit)
         row = {"group": g.name, "jit_cache": _jit_outcome(g_before)}
         with tracer.span("ming:dispatch", cat="runtime",
-                         args={"group": g.name}) as sargs:
-            if reg.enabled:
-                m_h2d.inc(_host_nbytes(env, _constants(g)),
-                          kind="constants")
+                         args={"group": g.name, **const_args}) as sargs:
             out = fn(env)
             if idx < len(transitions):
                 w, r = transitions[idx]
@@ -707,6 +794,7 @@ def run_compiled(design, env, *, interpret: bool | None = None,
             },
             "dma_write_bytes": sum(w for w, _ in transitions),
             "dma_read_bytes": sum(r for _, r in transitions),
+            "constants": placed,
         })
     return {v: env[v] for v in design.source.graph_outputs}
 
@@ -721,7 +809,8 @@ def _pad_rows(v, bucket: int):
 
 def run_compiled_batched(design, env, batch: int, *,
                          interpret: bool | None = None, jit: bool = True,
-                         stats_out: dict | None = None) -> dict:
+                         stats_out: dict | None = None,
+                         resident: ResidentConstants | None = None) -> dict:
     """Execute a :class:`~repro.core.compile_driver.CompiledDesign` over
     a batch in one device dispatch per group (ISSUE 7): every
     non-constant entry of ``env`` carries a leading axis of extent
@@ -734,11 +823,13 @@ def run_compiled_batched(design, env, batch: int, *,
     outputs as *device* arrays with a leading batch axis — the host
     conversion happens once at the caller's boundary, never per sample.
 
-    Spans: ``ming:inputs`` (the streamed inputs to the device, padded
+    Spans: ``ming:inputs`` (the constants through ``resident``, as in
+    :func:`run_compiled`, and the streamed inputs to the device, padded
     to their buckets), then per chunk and group ``ming:dispatch`` and,
     where the runner blocks (``stats_out``, a tracer or a registry, as
     in :func:`run_compiled`), ``ming:sync``.  With a registry ambient
-    it counts ``run_h2d_bytes_total{kind=inputs|constants}`` and
+    it counts ``run_h2d_bytes_total{kind=inputs|constants}``,
+    ``run_const_resident_total{outcome}`` and
     ``run_rows_total{kind=useful|padded}``.
 
     ``interpret=False`` is the explicit device-dispatch path (real
@@ -766,9 +857,11 @@ def run_compiled_batched(design, env, batch: int, *,
     src = design.source
     stream = [k for k in env
               if k in src.values and not src.values[k].is_constant]
-    const_env = {k: v for k, v in env.items() if k not in stream}
 
     with tracer.span("ming:inputs", cat="runtime", args={"batch": batch}):
+        const_env, placed = _place_constants(
+            design, {k: v for k, v in env.items() if k not in stream},
+            resident, reg)
         if reg.enabled:
             m_h2d.inc(_host_nbytes(env, stream), kind="inputs")
         on_device = {k: jnp.asarray(env[k]) for k in stream}
@@ -778,6 +871,8 @@ def run_compiled_batched(design, env, batch: int, *,
             for start, n, bucket in _batch_chunks(batch)
         ]
 
+    const_args = {"const_hit": placed["hit"],
+                  "const_upload": placed["upload"]}
     before = dict(exec_cache_stats)
     transitions = design.boundary_traffic()
     group_rows: dict[str, dict] = {}
@@ -794,7 +889,8 @@ def run_compiled_batched(design, env, batch: int, *,
                 fn = lower_group(g, interpret=interpret, jit=jit,
                                  batch=bucket)
                 with tracer.span("ming:dispatch", cat="runtime",
-                                 args={"group": g.name, "bucket": bucket}):
+                                 args={"group": g.name, "bucket": bucket,
+                                       **const_args}):
                     chunk_env.update(fn(chunk_env))
                 continue
             t0 = time.perf_counter()
@@ -806,11 +902,8 @@ def run_compiled_batched(design, env, batch: int, *,
             row["samples"] += n
             row["jit_cache"] = _jit_outcome(g_before)
             with tracer.span("ming:dispatch", cat="runtime",
-                             args={"group": g.name, "bucket": bucket}) \
-                    as sargs:
-                if reg.enabled:
-                    m_h2d.inc(_host_nbytes(chunk_env, _constants(g)),
-                              kind="constants")
+                             args={"group": g.name, "bucket": bucket,
+                                   **const_args}) as sargs:
                 out = fn(chunk_env)
                 sargs.update({"batch": n, "jit_cache": row["jit_cache"]})
                 if idx < len(transitions):
@@ -851,6 +944,7 @@ def run_compiled_batched(design, env, batch: int, *,
             "batch_buckets": [bucket for _, bucket, _ in chunks],
             "dma_write_bytes": sum(w for w, _ in transitions) * batch,
             "dma_read_bytes": sum(r for _, r in transitions) * batch,
+            "constants": placed,
         })
     return result
 
