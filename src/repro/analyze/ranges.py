@@ -79,6 +79,9 @@ class Interval:
     def relu(self) -> "Interval":
         return Interval(max(self.lo, 0), max(self.hi, 0))
 
+    def relu6(self) -> "Interval":
+        return Interval(min(max(self.lo, 0), 6), min(max(self.hi, 0), 6))
+
     def union(self, other: "Interval") -> "Interval":
         return Interval(min(self.lo, other.lo), max(self.hi, other.hi))
 
@@ -192,6 +195,8 @@ class _RangeWalker:
             return acc.floordiv(trip) if trip else ins[0]
         if kind == PayloadKind.RELU:
             return ins[0].relu()
+        if kind == PayloadKind.RELU6:
+            return ins[0].relu6()
         if kind == PayloadKind.SQUARED_RELU:
             r = ins[0].relu()
             return Interval(0, r.hi * r.hi)
@@ -215,6 +220,8 @@ class _RangeWalker:
         operand = self._value(e.operand) if e.operand else None
         if e.kind == PayloadKind.RELU:
             return cur.relu()
+        if e.kind == PayloadKind.RELU6:
+            return cur.relu6()
         if e.kind == PayloadKind.ADD and operand:
             return cur.add(operand)
         if e.kind == PayloadKind.MUL and operand:

@@ -59,6 +59,7 @@ from .builder import (
     AvgPool,
     Conv2D,
     Dense,
+    DepthwiseConv2D,
     Flatten,
     FrontendError,
     Graph,
@@ -114,6 +115,7 @@ __all__ = [
     "AvgPool",
     "Conv2D",
     "Dense",
+    "DepthwiseConv2D",
     "Flatten",
     "FrontendError",
     "Graph",

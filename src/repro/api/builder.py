@@ -5,16 +5,18 @@ value-by-value (``Value`` + ``make_conv2d_op`` + manual shape
 bookkeeping).  The builder replaces that with two combinator levels:
 
 * :class:`Graph` — an imperative builder with one method per layer kind
-  (``conv2d`` / ``relu`` / ``max_pool`` / ``avg_pool`` / ``dense`` /
-  ``add`` / …).  Every method infers the output shape from its inputs,
+  (``conv2d`` / ``depthwise_conv2d`` / ``relu`` / ``max_pool`` /
+  ``avg_pool`` / ``dense`` / ``add`` / …).  Every method infers the output shape from its inputs,
   validates ranks/extents/channel counts, and registers the values and
   the :class:`~repro.core.ir.GenericOp` in the underlying DFG.  Errors
   are :class:`FrontendError`\\ s that name the layer and say exactly
   which shape constraint broke.
 
 * :class:`Sequential` — a declarative layer list (:class:`Conv2D`,
-  :class:`ReLU`, :class:`MaxPool`, :class:`AvgPool`, :class:`Dense`,
-  :class:`Residual`, …) compiled through a :class:`Graph`.  ``Residual``
+  :class:`DepthwiseConv2D`, :class:`ReLU`, :class:`Activation` (ReLU6
+  is ``Activation(PayloadKind.RELU6)``), :class:`MaxPool`,
+  :class:`AvgPool`, :class:`Dense`, :class:`Residual`, …) compiled
+  through a :class:`Graph`.  ``Residual``
   runs its body layers and adds the skip back in (the diamond the
   FIFO-depth sizing of Sec. IV-C exists for).
 
@@ -37,6 +39,7 @@ from repro.core.ir import (
     Value,
     make_broadcast_binary_op,
     make_conv2d_op,
+    make_depthwise_conv2d_op,
     make_elementwise_op,
     make_flatten_op,
     make_matmul_op,
@@ -179,6 +182,47 @@ class Graph:
                 n=n, h_out=h_out, w_out=w_out, c_out=filters,
                 kh=kernel, kw=kernel, c_in=c_in, stride=stride,
                 elem_bits=x.elem_bits,
+            )
+        )
+        return self._ref(oname)
+
+    def depthwise_conv2d(self, x: TensorRef, kernel: int = 3,
+                         stride: int = 1, *, padding: str = "SAME",
+                         name: Optional[str] = None,
+                         weight: Optional[str] = None,
+                         out: Optional[str] = None) -> TensorRef:
+        """NHWC depthwise conv2d, depth multiplier 1: each channel
+        convolved with its own ``kernel × kernel`` filter (weight
+        ``(kernel, kernel, C)``).  Padding and output extents as
+        :meth:`conv2d`; the channel count is kept."""
+        nm = self._next("dwconv", name)
+        self._check(nm, x)
+        if x.rank != 4:
+            _fail(nm, f"depthwise_conv2d needs a rank-4 NHWC input, got "
+                      f"rank {x.rank} (shape {x.shape})")
+        if kernel < 1 or stride < 1:
+            _fail(nm, f"kernel/stride must be >= 1, got ({kernel}, {stride})")
+        if padding not in ("SAME", "VALID"):
+            _fail(nm, f'padding must be "SAME" or "VALID", got {padding!r}')
+        n, h, w, c = x.shape
+        if padding == "VALID":
+            if kernel > h or kernel > w:
+                _fail(nm, f"VALID depthwise kernel {kernel} exceeds the "
+                          f"spatial extents {h}x{w}")
+            h_out = (h - kernel) // stride + 1
+            w_out = (w - kernel) // stride + 1
+        else:
+            h_out = -(-h // stride)
+            w_out = -(-w // stride)
+        wref = self.constant((kernel, kernel, c), weight,
+                             elem_bits=x.elem_bits)
+        oname = out if out is not None else f"{nm}_out"
+        self.dfg.add_value(Value(oname, (n, h_out, w_out, c), x.elem_bits))
+        self.dfg.add_node(
+            make_depthwise_conv2d_op(
+                nm, x.name, wref.name, oname,
+                n=n, h_out=h_out, w_out=w_out, c=c, kh=kernel, kw=kernel,
+                stride=stride, elem_bits=x.elem_bits,
             )
         )
         return self._ref(oname)
@@ -385,6 +429,21 @@ class Conv2D:
 
 
 @dataclass(frozen=True)
+class DepthwiseConv2D:
+    kernel: int = 3
+    stride: int = 1
+    padding: str = "SAME"
+    name: Optional[str] = None
+    weight: Optional[str] = None
+    out: Optional[str] = None
+
+    def apply(self, g: Graph, x: TensorRef) -> TensorRef:
+        return g.depthwise_conv2d(x, self.kernel, self.stride,
+                                  padding=self.padding, name=self.name,
+                                  weight=self.weight, out=self.out)
+
+
+@dataclass(frozen=True)
 class ReLU:
     name: Optional[str] = None
     out: Optional[str] = None
@@ -501,7 +560,7 @@ class Residual:
         return g.add(cur, x, name=self.name, out=self.out)
 
 
-Layer = Union[Conv2D, ReLU, Activation, MaxPool, AvgPool, Dense, Residual,
+Layer = Union[Conv2D, DepthwiseConv2D, ReLU, Activation, MaxPool, AvgPool, Dense, Residual,
               Transpose, Flatten]
 
 

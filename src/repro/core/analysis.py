@@ -22,7 +22,7 @@ from __future__ import annotations
 import enum
 from dataclasses import dataclass
 
-from .ir import AffineExpr, GenericOp, IteratorType
+from .ir import AffineExpr, GenericOp, IteratorType, PayloadKind
 
 
 class KernelClass(str, enum.Enum):
@@ -155,8 +155,6 @@ def reorder_spec(
     the layout pass so all three agree on what a reorder op *is*
     without a payload flag.
     """
-    from .ir import PayloadKind  # local: avoid widening module surface
-
     if (
         op.payload != PayloadKind.IDENTITY
         or len(op.inputs) != 1
@@ -209,6 +207,41 @@ def reorder_spec(
             stride *= op.dim_extent(ax)
         return ("flatten", tuple(reversed(rev)))
     return None
+
+
+def channelwise_dims(op: GenericOp) -> tuple[int, ...]:
+    """Parallel loop dims that a sliding-window MAC reads through both
+    its streamed input and a constant input as single-dim subscripts:
+    the channels of a *grouped* reduction, each reduced against its own
+    filter.  Non-empty exactly for a depthwise conv
+    (:func:`repro.core.ir.make_depthwise_conv2d_op`: ``d3`` in the
+    input map ``(d0, d1·s+d4, d2·s+d5, d3)`` and the weight map
+    ``(d4, d5, d3)``); a full conv reads its output channel only
+    through the weight, a pool has no constant input.  The streamed
+    input is the first input whose map holds a composite subscript."""
+    if op.payload != PayloadKind.MAC or len(op.inputs) != 2:
+        return ()
+    if not detect_sliding_window(op).is_sliding_window:
+        return ()
+    stream = next(
+        (i for i, m in enumerate(op.input_maps)
+         if any(not e.is_single_dim() for e in m.results)), None)
+    if stream is None:
+        return ()
+
+    def singles(m) -> set[int]:
+        return {e.terms[0][0] for e in m.results if e.is_single_dim()}
+
+    other = op.input_maps[1 - stream]
+    shared = singles(op.input_maps[stream]) & singles(other)
+    return tuple(sorted(d for d in shared if op.is_parallel_dim(d)))
+
+
+def is_depthwise(op: GenericOp) -> bool:
+    """A sliding-window MAC whose reduction is grouped per channel
+    (:func:`channelwise_dims`) — the depthwise conv, told apart from a
+    full conv by its maps alone."""
+    return bool(channelwise_dims(op))
 
 
 def classify_kernel(op: GenericOp) -> KernelInfo:

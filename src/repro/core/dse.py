@@ -11,7 +11,10 @@ The ILP::
 is solved *exactly* with branch-and-bound over divisor lattices — the
 paper's point is that streaming collapses the design space enough that a
 lightweight solver suffices; we lean on the same property (candidate sets
-are divisor lists, typically a few dozen entries per node).
+are divisor lists, typically a few dozen entries per node).  A search
+that outgrows :data:`ILP_EXACT_BUDGET` nodes (deep groups of small
+nodes) keeps the best assignment a fastest-first search finds in as
+many again, and says so (``DseResult.exact``).
 
 The decision variable is one unroll factor per dataflow node.  Reduction
 loops unroll first (they add MACs/cycle without widening streams); once a
@@ -173,6 +176,21 @@ class DseResult:
     explored: int = 0
     #: nodes mapped with partial weight streaming (node -> tile count > 1)
     weight_tiles: dict[str, int] = field(default_factory=dict)
+    #: the search proved this assignment optimal; ``False`` when it ran
+    #: past :data:`ILP_EXACT_BUDGET` and kept the best one it found
+    exact: bool = True
+
+
+#: branch-and-bound nodes :func:`solve_ilp` visits in its exact order
+#: before it falls back to a fastest-first search of the same size.
+#: The paper suite, the zoo and VGG-16 stay far below it; a deep group
+#: of small nodes (MobileNetV2's 1×1 and depthwise convs, each with a
+#: dozen or more unroll factors) does not.
+ILP_EXACT_BUDGET = 20_000
+
+
+class _OverBudget(Exception):
+    pass
 
 
 def solve_ilp(
@@ -249,7 +267,8 @@ def solve_ilp(
             producers_of[s.consumer].append(s.producer)
 
     order = [n.name for n in nodes]
-    best: dict = {"cycles": math.inf, "assign": None, "explored": 0}
+    best: dict = {"cycles": math.inf, "assign": None, "explored": 0,
+                  "limit": ILP_EXACT_BUDGET}
     # optimistic per-node lower bounds for pruning: cycles drive the
     # branch-and-bound incumbent check, bram/dsp prove infeasibility of a
     # partial assignment without enumerating its subtree (this is what
@@ -271,6 +290,8 @@ def solve_ilp(
         i: int, assign: dict[str, UnrollChoice], dsp: int, bram: int, cycles: int
     ) -> None:
         best["explored"] += 1
+        if best["explored"] > best["limit"]:
+            raise _OverBudget
         if cycles + suffix_cycles[i] >= best["cycles"]:
             return
         if i == len(order):
@@ -292,7 +313,22 @@ def solve_ilp(
                     cycles + choice.cycles)
             del assign[name]
 
-    recurse(0, {}, 0, 0, 0)
+    exact = True
+    try:
+        recurse(0, {}, 0, 0, 0)
+    except _OverBudget:
+        # too many shapes to prove the optimum: search again with each
+        # node's fastest candidates first, so that the first leaves are
+        # good incumbents and the cycle bound prunes hard, for as many
+        # nodes again, keeping the best assignment either search found
+        exact = False
+        for name in order:
+            cand[name] = sorted(cand[name], key=lambda c: c.cycles)
+        best["limit"] += ILP_EXACT_BUDGET
+        try:
+            recurse(0, {}, 0, 0, 0)
+        except _OverBudget:
+            pass
 
     if best["assign"] is None:
         # infeasible under the budgets — report unroll=1 estimate
@@ -316,6 +352,7 @@ def solve_ilp(
         feasible=True,
         explored=best["explored"],
         weight_tiles=tiles,
+        exact=exact,
     )
 
 
@@ -488,3 +525,58 @@ def plan_conv_rows(
         rows *= 2
     assert best is not None, "no feasible conv row tiling"
     return best
+
+
+#: VMEM the depthwise kernel's working set may take: half the scoped
+#: budget, so the pipeline's double buffers never crowd it out
+DW_VMEM_BUDGET = TPU_V5E.vmem_bytes // 2
+
+
+def plan_dwconv_rows(
+    *,
+    h: int,
+    w: int,
+    c: int,
+    kh: int,
+    stride: int = 1,
+    vmem_budget: int | None = None,
+    spec: TpuSpec = TPU_V5E,
+    bytes_per_el: int = 4,
+) -> TpuBlockPlan:
+    """Rows-per-block for the depthwise streaming kernel
+    (``kernels/depthwise_stream.py``) over a padded frame of ``h`` rows
+    and ``w`` columns.
+
+    The VMEM working set of one grid step, every array lane-padded to
+    128 channels: the input block double-buffered, the line buffer
+    (``kh - s`` carried rows, the block, ``s - 1`` spare rows), the
+    output block double-buffered and the accumulator.  The fewest row
+    blocks whose working set fits ``vmem_budget``
+    (:data:`DW_VMEM_BUDGET` by default) wins, and the rows are then
+    spread evenly over them (a multiple of ``stride``), so the frame
+    pads by less than one stride-rounded block.  The work is
+    ``K·K`` lane-wise MACs per output point, on the VPU."""
+    budget = vmem_budget or DW_VMEM_BUDGET
+    # the kernel's channel block: 128 lanes where they divide, else all
+    block = spec.mxu_dim if c % spec.mxu_dim == 0 else c
+    lanes = -(-block // spec.mxu_dim) * spec.mxu_dim
+    w_out = -(-(w - kh + 1) // stride)
+    carry = max(kh - stride, 0)
+    row_in = w * lanes * bytes_per_el
+    row_out = w_out * lanes * 4  # float32 / int32 accumulators
+
+    def vmem(rows: int) -> int:
+        r_out = rows // stride
+        return (2 * rows * row_in + (carry + rows + stride - 1) * row_in
+                + 3 * r_out * row_out)
+
+    top = -(-h // stride) * stride
+    rows = stride
+    while rows + stride <= top and vmem(rows + stride) <= budget:
+        rows += stride
+    blocks = -(-h // rows)
+    rows = -(-(-(-h // blocks)) // stride) * stride
+    macs = -(-h // stride) * w_out * c * kh * kh
+    cycles = macs / spec.vpu_lanes
+    return TpuBlockPlan("dwconv_rows", {"rows": rows, "blocks": blocks},
+                        cycles, vmem(rows), 0.0)

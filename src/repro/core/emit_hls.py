@@ -28,7 +28,7 @@ from __future__ import annotations
 import math
 from typing import Iterable
 
-from .analysis import KernelClass, window_geometry
+from .analysis import KernelClass, channelwise_dims, window_geometry
 from .dse import DseResult
 from .ir import PayloadKind
 from .streaming import NodePlan, StreamingPlan
@@ -41,6 +41,7 @@ _PAYLOAD_EXPR = {
     PayloadKind.MAX: "out_v = (a_v > b_v) ? a_v : b_v;",
     PayloadKind.AVG: "acc += (accum_t)win[i];  // avg-pool accumulate",
     PayloadKind.RELU: "out_v = (in_v > 0) ? in_v : (elem_t)0;",
+    PayloadKind.RELU6: "out_v = (in_v > 0) ? ((in_v < 6) ? in_v : (elem_t)6) : (elem_t)0;",
     PayloadKind.SQUARED_RELU: "out_v = (in_v > 0) ? (elem_t)(in_v * in_v) : (elem_t)0;",
     PayloadKind.IDENTITY: "out_v = in_v;",
     PayloadKind.MUL: "out_v = a_v * b_v;",
@@ -51,6 +52,7 @@ _PAYLOAD_EXPR = {
 #: for MAC nodes, ``out_v`` otherwise), {k} the on-chip constant operand.
 _EPILOGUE_EXPR = {
     PayloadKind.RELU: "{v} = ({v} > 0) ? {v} : 0;",
+    PayloadKind.RELU6: "{v} = ({v} > 0) ? (({v} < 6) ? {v} : 6) : 0;",
     PayloadKind.SQUARED_RELU: "{v} = ({v} > 0) ? {v} * {v} : 0;",
     PayloadKind.IDENTITY: "",
     PayloadKind.EXP: "{v} = hls::exp({v});",
@@ -217,9 +219,14 @@ def emit_node(plan: NodePlan, unroll: int, width: int,
 
     if plan.kernel_class == KernelClass.SLIDING_WINDOW:
         geo = window_geometry(op, plan.info)
+        # a grouped reduction (the depthwise conv) keeps every channel
+        # lane of a line: K·K MACs per lane against its own filter
+        lanes = math.prod(op.dim_sizes[d] for d in channelwise_dims(op))
         if len(geo.window_dims) >= 2:
             k_outer = geo.window_extents[0]
             line_len = geo.input_extents[-1]
+            if lanes > 1:
+                line_len = f"{line_len} * {lanes}"
             stride_note = ""
             if op.payload == PayloadKind.MAC and geo.stride > 1:
                 # strided conv: the line shifter still holds K-1 input
@@ -239,6 +246,11 @@ def emit_node(plan: NodePlan, unroll: int, width: int,
                 f"#pragma HLS ARRAY_PARTITION variable=line_buf dim=1 complete"
             )
         win = math.prod(geo.window_extents)
+        if channelwise_dims(op):
+            lines.append(
+                f"  // depthwise: {win} (K·K) MACs per channel lane, each "
+                "lane against its own filter"
+            )
         lines.append(f"  elem_t win[{win}];")
         lines.append("#pragma HLS ARRAY_PARTITION variable=win complete")
         lines.append(f"  elem_t wgt[{win}];")

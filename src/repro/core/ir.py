@@ -162,6 +162,7 @@ class PayloadKind(str, enum.Enum):
     #                           accumulate ADDs, divide once on the
     #                           stream-exit datapath (the DIV exit path)
     RELU = "relu"             # out = max(in0, 0)
+    RELU6 = "relu6"           # out = min(max(in0, 0), 6)
     SQUARED_RELU = "squared_relu"
     IDENTITY = "identity"
     EXP = "exp"
@@ -177,6 +178,7 @@ PAYLOAD_COSTS: dict[PayloadKind, tuple[int, int]] = {
     # as a constant-reciprocal multiply (Vitis lowers /const to mul+shift)
     PayloadKind.AVG: (1, 1),
     PayloadKind.RELU: (0, 1),
+    PayloadKind.RELU6: (0, 2),  # two compares, no multiply
     PayloadKind.SQUARED_RELU: (1, 1),
     PayloadKind.IDENTITY: (0, 0),
     PayloadKind.EXP: (4, 4),  # poly approx budget
@@ -545,6 +547,53 @@ def make_conv2d_op(
         indexing_maps=(imap, wmap, omap),
         iterator_types=(P, P, P, P, R, R, R),
         dim_sizes=(n, h_out, w_out, c_out, kh, kw, c_in),
+        payload=PayloadKind.MAC,
+        elem_bits=elem_bits,
+    )
+
+
+def make_depthwise_conv2d_op(
+    name: str,
+    input_name: str,
+    weight_name: str,
+    output_name: str,
+    *,
+    n: int,
+    h_out: int,
+    w_out: int,
+    c: int,
+    kh: int,
+    kw: int,
+    stride: int = 1,
+    elem_bits: int = 8,
+) -> GenericOp:
+    """NHWC depthwise conv2d (depth multiplier 1) as linalg.generic: the
+    sliding-window MAC that reduces over the window only, each channel
+    against its own ``kh × kw`` filter.
+
+    dims: (d0=n, d1=h, d2=w, d3=c, d4=r, d5=s);
+    input map:  (d0, d1*stride + d4, d2*stride + d5, d3)
+    weight map: (d4, d5, d3)
+    output map: (d0, d1, d2, d3)
+
+    The channel ``d3`` is parallel and read by both the streamed input
+    and the weight — the grouped reduction the analyses recognise
+    (:func:`repro.core.analysis.channelwise_dims`).
+    """
+    d = AffineExpr.dim
+    imap = AffineMap.of(
+        6, [d(0), d(1, stride) + d(4), d(2, stride) + d(5), d(3)]
+    )
+    wmap = AffineMap.of(6, [d(4), d(5), d(3)])
+    omap = AffineMap.of(6, [d(0), d(1), d(2), d(3)])
+    P, R = IteratorType.PARALLEL, IteratorType.REDUCTION
+    return GenericOp(
+        name=name,
+        inputs=(input_name, weight_name),
+        output=output_name,
+        indexing_maps=(imap, wmap, omap),
+        iterator_types=(P, P, P, P, R, R),
+        dim_sizes=(n, h_out, w_out, c, kh, kw),
         payload=PayloadKind.MAC,
         elem_bits=elem_bits,
     )

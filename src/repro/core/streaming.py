@@ -27,6 +27,7 @@ from .analysis import (
     IteratorClasses,
     KernelClass,
     KernelInfo,
+    channelwise_dims,
     classify_kernel,
     reorder_spec,
     window_geometry,
@@ -192,14 +193,17 @@ def plan_node(op: GenericOp, dfg: DFG) -> NodePlan:
         geo = window_geometry(op, info)
         idx, streamed = _streamed_input(op, dfg)
         assert streamed is not None, f"{op.name}: sliding window with no stream input"
-        # channel-like reduction dims of the *streamed* input: single-dim
-        # reduction subscripts in its map (e.g. c_in for NHWC conv).
+        # channel-like dims of the *streamed* input: single-dim
+        # reduction subscripts in its map (c_in for an NHWC conv), and
+        # the per-channel lanes of a grouped reduction (the depthwise
+        # conv's c, parallel but buffered the same way)
         smap = op.input_maps[idx]
+        lanes = set(channelwise_dims(op))
         chan = 1
         for expr in smap.results:
             if expr.is_single_dim():
                 (d, _), = expr.terms
-                if op.is_reduction_dim(d):
+                if op.is_reduction_dim(d) or d in lanes:
                     chan *= op.dim_extent(d)
         # line buffer: (K_outer - 1) lines; a line spans the *input* extent
         # of the innermost window axis times the channel depth.
@@ -273,7 +277,10 @@ def plan_node(op: GenericOp, dfg: DFG) -> NodePlan:
     # dims eligible for partial weight streaming: parallel non-window
     # subscripts of a constant input (c_out for conv weights, n_out for
     # matmul weights) — tiling them splits the const buffer cleanly.
-    window = set(info.classes.window)
+    # A grouped reduction's channel also indexes the streamed input, so
+    # a weight tile would need its own slice of the stream: not a tile
+    # axis (its K·K·C weights are small anyway).
+    window = set(info.classes.window) | set(channelwise_dims(op))
     tile_dims: set[int] = set()
     for i, name in enumerate(op.inputs):
         if not dfg.values[name].is_constant:

@@ -1,8 +1,9 @@
 """Portable JSON "model cards": a self-contained graph interchange format.
 
 A model card is a JSON document that round-trips any *pre-pass* builder
-graph — inputs, a flat layer list (conv2d / pools / dense / relu /
-activation / add / transpose / flatten / bare constants), outputs, and
+graph — inputs, a flat layer list (conv2d / depthwise_conv2d / pools /
+dense / relu / activation / add / transpose / flatten / bare constants),
+outputs, and
 optionally the weights (base64 raw bytes) — with **node-for-node
 fidelity**: ``import_card(export_card(g))`` rebuilds a DFG that compares
 dataclass-equal to ``g`` (``tests/test_modelcard.py`` pins this as a
@@ -13,6 +14,14 @@ its own output in memory and diffs the reconstruction against the
 source graph before returning, so a graph the schema cannot express
 fails loudly at export time (fused epilogues, exotic maps) instead of
 producing a lossy card.
+
+A ``depthwise_conv2d`` record (``input``, ``kernel``, ``stride``,
+``weight``, ``out``, and ``padding: "VALID"`` where it is not SAME) is
+the depthwise conv of ``repro.core.ir.make_depthwise_conv2d_op``: one
+``(kernel, kernel, C)`` weight, maps ``(d0, d1·s+d4, d2·s+d5, d3)``,
+``(d4, d5, d3)`` → ``(d0, d1, d2, d3)``, the channel count kept.
+Activations are ``relu`` records or ``activation`` records whose
+``kind`` is a payload kind (``relu6`` for the ReLU6 clamp).
 
 Cards are the zoo's storage format (``repro.frontends.zoo``), the CLI's
 ``python -m repro compile model.json`` input, and the stable on-disk
@@ -28,7 +37,12 @@ from typing import Mapping, Optional
 
 import numpy as np
 
-from repro.core.analysis import KernelClass, classify_kernel, reorder_spec
+from repro.core.analysis import (
+    KernelClass,
+    classify_kernel,
+    is_depthwise,
+    reorder_spec,
+)
 from repro.core.ir import DFG, GenericOp, PayloadKind
 
 from .base import ImportedModel
@@ -38,8 +52,8 @@ SCHEMA_VERSION = 1
 
 #: ops a v1 card can express (the error message vocabulary)
 CARD_OPS = (
-    "conv2d", "max_pool", "avg_pool", "dense", "relu", "activation",
-    "add", "transpose", "flatten", "constant",
+    "conv2d", "depthwise_conv2d", "max_pool", "avg_pool", "dense", "relu",
+    "activation", "add", "transpose", "flatten", "constant",
 )
 
 
@@ -80,6 +94,24 @@ def _node_record(dfg: DFG, op: GenericOp) -> dict:
                 "order": list(arg), "out": op.output}
     info = classify_kernel(op)
     if info.kernel_class == KernelClass.SLIDING_WINDOW:
+        if op.payload == PayloadKind.MAC and is_depthwise(op):
+            _require(op.n_dims == 6 and info.dilation == 1,
+                     f"{op.name}: only undilated depthwise convs with one "
+                     "filter per channel are expressible")
+            stream, weight = op.inputs
+            _require(not dfg.values[stream].is_constant
+                     and dfg.values[weight].is_constant,
+                     f"{op.name}: depthwise conv needs a stream input "
+                     "then a const weight")
+            kh, kw = op.dim_sizes[4], op.dim_sizes[5]
+            _require(kh == kw, f"{op.name}: non-square kernel {kh}x{kw}")
+            rec = {"op": "depthwise_conv2d", "name": op.name,
+                   "input": stream, "kernel": kh, "stride": info.stride,
+                   "weight": weight, "out": op.output}
+            h_in = dfg.values[stream].shape[1]
+            if op.dim_sizes[1] != -(-h_in // info.stride):
+                rec["padding"] = "VALID"
+            return rec
         if op.payload == PayloadKind.MAC and op.n_dims == 7:
             _require(info.dilation == 1,
                      f"{op.name}: dilated convs are not expressible (v1)")
@@ -175,7 +207,7 @@ def export_card(
     created = set()
     for op in dfg.nodes:
         rec = _node_record(dfg, op)
-        if rec["op"] in ("conv2d", "dense"):
+        if rec["op"] in ("conv2d", "depthwise_conv2d", "dense"):
             created.add(rec["weight"])
         # any other constant operand needs an explicit record first
         for v in op.inputs:
@@ -285,6 +317,13 @@ def _build_dfg(card: dict) -> DFG:
                 refs[rec["out"]] = g.conv2d(
                     ref(rec, "input"), rec["filters"],
                     kernel=rec.get("kernel", 3), stride=rec.get("stride", 1),
+                    padding=rec.get("padding", "SAME"),
+                    name=rec["name"], weight=rec["weight"], out=rec["out"],
+                )
+            elif op == "depthwise_conv2d":
+                refs[rec["out"]] = g.depthwise_conv2d(
+                    ref(rec, "input"), kernel=rec.get("kernel", 3),
+                    stride=rec.get("stride", 1),
                     padding=rec.get("padding", "SAME"),
                     name=rec["name"], weight=rec["weight"], out=rec["out"],
                 )

@@ -8,11 +8,12 @@ from the ``.onnx`` bytes — the container ships no ONNX, and a model zoo
 frontend that silently required one would never run in CI.
 
 Supported operator subset (everything the builder can express):
-``Conv`` (groups=1, dilation 1, any uniform stride, SAME_UPPER / VALID
-/ equivalent explicit pads), ``BatchNormalization`` (inference form,
+``Conv`` (groups=1, or group = C_in = C_out as a depthwise conv;
+dilation 1, any uniform stride, SAME_UPPER / VALID / equivalent explicit
+pads), ``BatchNormalization`` (inference form,
 folded into the producing Conv's weights and bias at import),
 ``GlobalAveragePool`` (square maps, via the AVG epilogue's DIV exit
-path), ``Relu``, ``MaxPool`` / ``AveragePool`` (square VALID windows),
+path), ``Relu``, ``Clip`` (min 0, max 6 only: ReLU6), ``MaxPool`` / ``AveragePool`` (square VALID windows),
 ``Gemm`` (α=1, transA=0, β∈{0,1}), ``Add``, ``Flatten`` (axis=1).
 Anything else raises :class:`OnnxImportError` naming the node and the
 constraint.  Per-channel biases (Conv B, Gemm C) import as rank-1
@@ -58,7 +59,7 @@ NCHW2NHWC = (0, 2, 3, 1)
 NHWC2NCHW = (0, 3, 1, 2)
 
 SUPPORTED_OPS = ("Conv", "BatchNormalization", "GlobalAveragePool", "Relu",
-                 "MaxPool", "AveragePool", "Gemm", "Add", "Flatten")
+                 "Clip", "MaxPool", "AveragePool", "Gemm", "Add", "Flatten")
 
 
 class OnnxImportError(ValueError):
@@ -578,6 +579,7 @@ def _fold_batchnorm(og: OnnxGraph) -> None:
 
 def _to_builder(og: OnnxGraph, model_name: str) -> ImportedModel:
     from repro.api.builder import FrontendError, Graph, TensorRef
+    from repro.core.ir import PayloadKind
 
     g = Graph(model_name)
     names = _Names()
@@ -610,9 +612,7 @@ def _to_builder(og: OnnxGraph, model_name: str) -> ImportedModel:
                   "initializer")
         if w.ndim != 4:
             _fail(f"Conv {node.name!r}: weight rank {w.ndim} != 4")
-        if node.attrs.get("group", 1) != 1:
-            _fail(f"Conv {node.name!r}: grouped convs are unsupported "
-                  f"(group={node.attrs['group']})")
+        group = node.attrs.get("group", 1)
         dil = node.attrs.get("dilations")
         if dil and any(d != 1 for d in dil):
             _fail(f"Conv {node.name!r}: dilations {dil} are unsupported")
@@ -625,13 +625,27 @@ def _to_builder(og: OnnxGraph, model_name: str) -> ImportedModel:
         x = ref(node, xn)
         if x.rank != 4:
             _fail(f"Conv {node.name!r}: input rank {x.rank} != 4 (NCHW)")
+        c_in = int(x.shape[1])
+        depthwise = group == c_in == int(w.shape[0]) and w.shape[1] == 1
+        if group != 1 and not depthwise:
+            _fail(f"Conv {node.name!r}: grouped convs are unsupported "
+                  f"(group={group}; only group == C_in == C_out, the "
+                  "depthwise conv, is)")
         padding = _resolve_conv_padding(node, kernel, stride,
                                         int(x.shape[2]), int(x.shape[3]))
         h = g.transpose(x, NCHW2NHWC)
         wname = weight_name(wn)
-        h = g.conv2d(h, int(w.shape[0]), kernel=kernel, stride=stride,
-                     padding=padding, weight=wname)
-        params[wname] = np.ascontiguousarray(np.transpose(w, (2, 3, 1, 0)))
+        if depthwise:
+            h = g.depthwise_conv2d(h, kernel=kernel, stride=stride,
+                                   padding=padding, weight=wname)
+            # (C, 1, k, k) -> (k, k, C)
+            params[wname] = np.ascontiguousarray(
+                np.transpose(w[:, 0], (1, 2, 0)))
+        else:
+            h = g.conv2d(h, int(w.shape[0]), kernel=kernel, stride=stride,
+                         padding=padding, weight=wname)
+            params[wname] = np.ascontiguousarray(
+                np.transpose(w, (2, 3, 1, 0)))
         if len(node.inputs) == 3:
             b = og.initializers.get(node.inputs[2])
             if b is None:
@@ -734,8 +748,30 @@ def _to_builder(og: OnnxGraph, model_name: str) -> ImportedModel:
             return
         refs[node.outputs[0]] = g.flatten(x)
 
+    def clip_bound(node: OnnxNode, attr: str, pos: int):
+        """A Clip bound: the attribute (opset < 11) or the optional
+        scalar initializer input (opset >= 11); ``None`` if absent."""
+        if attr in node.attrs:
+            return float(node.attrs[attr])
+        if len(node.inputs) > pos and node.inputs[pos]:
+            arr = og.initializers.get(node.inputs[pos])
+            if arr is None or arr.size != 1:
+                _fail(f"Clip {node.name!r}: {attr} {node.inputs[pos]!r} "
+                      "must be a scalar initializer")
+            return float(arr.reshape(()))
+        return None
+
+    def handle_clip(node: OnnxNode) -> None:
+        lo, hi = clip_bound(node, "min", 1), clip_bound(node, "max", 2)
+        if (lo, hi) != (0.0, 6.0):
+            _fail(f"Clip {node.name!r}: only Clip(min=0, max=6), ReLU6, "
+                  f"is supported (min={lo}, max={hi})")
+        refs[node.outputs[0]] = g.activation(
+            ref(node, node.inputs[0]), PayloadKind.RELU6, "relu6")
+
     handlers = {
         "Conv": handle_conv,
+        "Clip": handle_clip,
         "Relu": lambda n: refs.__setitem__(
             n.outputs[0], g.relu(ref(n, n.inputs[0]))
         ),

@@ -20,6 +20,10 @@ pool pyramids, and residual trunks feeding a head:
                         global average pool and the dense head — the
                         strided streaming conv path end to end.
 
+:func:`mobilenet_v2` builds MobileNetV2 (depthwise convs, ReLU6, folded
+biases, inverted residuals) at any size and width; it stays out of the
+registry below, which the smoke suite compiles whole.
+
 Every entry is a plain builder graph (so the whole pass pipeline,
 partitioner, and both backends apply unchanged), is registered in the
 benchmark suite (``repro.api.suite()`` → per-target BENCH_smoke rows),
@@ -36,12 +40,13 @@ from repro.api.builder import (
     Conv2D,
     Dense,
     Flatten,
+    Graph,
     MaxPool,
     ReLU,
     Residual,
     Sequential,
 )
-from repro.core.ir import DFG
+from repro.core.ir import DFG, PayloadKind
 
 from .modelcard import export_card
 
@@ -119,6 +124,67 @@ def resnet_mini(n_size: int = 16, c: int = 8, classes: int = 10) -> DFG:
         input_shape=(1, n_size, n_size, 3),
         name=f"resnet_mini_{n_size}",
     ).build()
+
+
+#: MobileNetV2's inverted-residual stages (Sandler et al. 2018, Table 2):
+#: expansion t, output channels c, repeats n, first stride s
+MOBILENET_V2_STAGES = (
+    (1, 16, 1, 1), (6, 24, 2, 2), (6, 32, 3, 2), (6, 64, 4, 2),
+    (6, 96, 3, 1), (6, 160, 3, 2), (6, 320, 1, 1),
+)
+
+
+def _divisible(v: float, by: int = 8) -> int:
+    """Channels scaled by a width multiplier, rounded to a multiple of
+    ``by`` and never more than 10% below ``v`` (the reference
+    implementations' rule)."""
+    out = max(by, int(v + by / 2) // by * by)
+    return out + by if out < 0.9 * v else out
+
+
+def mobilenet_v2(n_size: int = 224, width_mult: float = 1.0,
+                 classes: int = 1000) -> DFG:
+    """MobileNetV2 in inference form, BatchNorm folded: each conv is
+    followed by a per-channel bias add, then ReLU6 except after the
+    linear bottleneck projections.  A 3×3 stride-2 stem, the 17
+    inverted-residual blocks of :data:`MOBILENET_V2_STAGES` (1×1
+    expansion, 3×3 depthwise conv, 1×1 projection; a residual add where
+    the stride is 1 and the channels match), a 1×1 conv to 1280, a
+    global average pool and the dense classifier.  Padding is SAME.
+    Channels scale with ``width_mult`` (the 1280 only above 1.0).
+    Not in :data:`ZOO`: at 224² it is a benchmark configuration
+    (``bench/configs/mobilenetv2.json`` is its card), not a smoke
+    model."""
+    relu6 = PayloadKind.RELU6
+    default = (n_size, width_mult, classes) == (224, 1.0, 1000)
+    g = Graph("mobilenetv2" if default
+              else f"mobilenetv2_{n_size}_{width_mult:g}_{classes}")
+
+    def biased(h):
+        return g.add(h, g.constant((h.shape[-1],)))
+
+    def conv(h, filters, kernel=1, stride=1, act=True):
+        h = biased(g.conv2d(h, filters, kernel, stride))
+        return g.activation(h, relu6, "relu6") if act else h
+
+    h = conv(g.input((1, n_size, n_size, 3)), _divisible(32 * width_mult),
+             kernel=3, stride=2)
+    for t, c, n, s in MOBILENET_V2_STAGES:
+        out_c = _divisible(c * width_mult)
+        for i in range(n):
+            stride = s if i == 0 else 1
+            skip = h
+            if t != 1:
+                h = conv(h, skip.shape[-1] * t)
+            h = biased(g.depthwise_conv2d(h, 3, stride))
+            h = g.activation(h, relu6, "relu6")
+            h = conv(h, out_c, act=False)
+            if stride == 1 and skip.shape[-1] == out_c:
+                h = g.add(h, skip)
+    h = conv(h, _divisible(1280 * max(1.0, width_mult)))
+    h = g.flatten(g.avg_pool(h, h.shape[1]))
+    g.output(biased(g.dense(h, classes)))
+    return g.build()
 
 
 #: the registry the CLI (`python -m repro zoo`), the benchmark suite,

@@ -39,15 +39,22 @@ from repro.instrument import metrics as _metrics
 
 from repro.core.analysis import (
     KernelClass,
+    channelwise_dims,
     classify_kernel,
     conv_spatial_pads,
     einsum_spec,
     reorder_spec,
     window_geometry,
 )
-from repro.core.dse import plan_attention_blocks, plan_conv_rows, plan_matmul_blocks
+from repro.core.dse import (
+    plan_attention_blocks,
+    plan_conv_rows,
+    plan_dwconv_rows,
+    plan_matmul_blocks,
+)
 from repro.core.ir import PayloadKind
 from . import conv2d_stream as _conv
+from . import depthwise_stream as _dw
 from . import flash_attention as _flash
 from . import fused_mlp as _mlp
 from . import mamba2_ssd as _ssd
@@ -110,9 +117,38 @@ def _conv_pads(
     return (int(pt), int(pb)), (int(pl), int(pr))
 
 
+def _stream_frame(x, kh: int, kw: int, stride: int, padding: Padding,
+                  rows_per_block: int | None, plan_rows):
+    """The padded frame a line-buffer streaming kernel reads, and where
+    its output lies: ``(frame, rows_per_block, h_out, w_out, c_skip)``.
+    ``plan_rows(hp, wp)`` picks the rows per block (a multiple of the
+    stride) where the caller gave none; the bottom pads to a multiple
+    of it.  See :func:`conv2d_stream` for the alignment."""
+    _, h, ww, _ = x.shape
+    (pad_t, pad_b), (pad_l, pad_r) = _conv_pads(h, ww, kh, kw, stride, padding)
+    h_out = (h + pad_t + pad_b - kh) // stride + 1
+    w_out = (ww + pad_l + pad_r - kw) // stride + 1
+
+    carry = _conv.line_buffer_rows(kh, stride)
+    c_skip = -(-carry // stride)            # garbage leading output rows
+    align = c_skip * stride - carry         # extra zero rows on top
+    hp = align + pad_t + h + pad_b
+    if rows_per_block is None:
+        rows_per_block = plan_rows(hp, ww + pad_l + pad_r)
+    # rows_per_block must divide hp — pad the bottom if necessary
+    hp_pad = _round_up(hp, rows_per_block)
+    x_p = jnp.pad(
+        x,
+        ((0, 0), (align + pad_t, pad_b + (hp_pad - hp)),
+         (pad_l, pad_r), (0, 0)),
+    )
+    return x_p, rows_per_block, h_out, w_out, c_skip
+
+
 def conv2d_stream(
     x: jax.Array,            # (B, H, W, Cin)
     w: jax.Array,            # (KH, KW, Cin, Cout)
+    bias: jax.Array | None = None,   # (Cout,)
     *,
     stride: int = 1,
     padding: Padding = "SAME",
@@ -130,8 +166,9 @@ def conv2d_stream(
     at most 8 bits; wider integers raise ``KernelDtypeError``.
 
     ``epilogue`` fuses an elementwise tail into the kernel's writeback
-    (``"relu"`` | ``"squared_relu"``) — the TPU realization of the pass
-    pipeline's conv+activation fusion (``repro.passes.fusion``);
+    (``"relu"`` | ``"relu6"`` | ``"squared_relu"``), after ``bias`` (one
+    value per output channel) — the TPU realization of the pass
+    pipeline's conv+bias+activation fusion (``repro.passes.fusion``);
     ``fuse_relu=True`` remains as sugar for ``epilogue="relu"``.
 
     Stride-s alignment: the kernel emits one output row per ``stride``
@@ -145,38 +182,62 @@ def conv2d_stream(
     ``C = c = kh - 1``, ``A = 0``, slice ``[kh-1 : kh-1+h]``.
     """
     interpret = _auto_interpret(interpret)
-    b, h, ww, cin = x.shape
-    kh, kw, _, cout = w.shape
-    (pad_t, pad_b), (pad_l, pad_r) = _conv_pads(h, ww, kh, kw, stride, padding)
-    h_out = (h + pad_t + pad_b - kh) // stride + 1
-    w_out = (ww + pad_l + pad_r - kw) // stride + 1
+    kh, kw, cin, cout = w.shape
 
-    carry = _conv.line_buffer_rows(kh, stride)
-    c_skip = -(-carry // stride)            # garbage leading output rows
-    align = c_skip * stride - carry         # extra zero rows on top
-    hp = align + pad_t + h + pad_b
-    if rows_per_block is None:
+    def plan_rows(hp: int, wp: int) -> int:
         plan = plan_conv_rows(
-            h=hp, w=ww + pad_l + pad_r, c_in=cin, c_out=cout, kh=kh, kw=kw,
+            h=hp, w=wp, c_in=cin, c_out=cout, kh=kh, kw=kw,
             bytes_per_el=x.dtype.itemsize,
         )
-        rows_per_block = _round_up(plan.blocks["rows"], stride)
-    # rows_per_block must divide hp — pad the bottom if necessary
-    hp_pad = _round_up(hp, rows_per_block)
-    x_p = jnp.pad(
-        x,
-        ((0, 0), (align + pad_t, pad_b + (hp_pad - hp)),
-         (pad_l, pad_r), (0, 0)),
-    )
+        return _round_up(plan.blocks["rows"], stride)
+
+    x_p, rows_per_block, h_out, w_out, c_skip = _stream_frame(
+        x, kh, kw, stride, padding, rows_per_block, plan_rows)
     out = _conv.conv2d_stream_pallas(
         x_p,
         w,
+        bias,
         rows_per_block=rows_per_block,
         w_out=w_out,
         stride=stride,
         fuse_relu=fuse_relu,
         epilogue=epilogue,
         interpret=interpret,
+    )
+    return out[:, c_skip : c_skip + h_out]
+
+
+def dwconv2d_stream(
+    x: jax.Array,            # (B, H, W, C)
+    w: jax.Array,            # (KH, KW, C)
+    bias: jax.Array | None = None,   # (C,)
+    *,
+    stride: int = 1,
+    padding: Padding = "SAME",
+    epilogue: str | None = None,
+    rows_per_block: int | None = None,
+    interpret: bool | None = None,
+) -> jax.Array:
+    """NHWC depthwise conv (one ``KH × KW`` filter per channel) via the
+    line-buffer streaming VPU kernel (``kernels/depthwise_stream.py``):
+    stride ``s``, SAME / VALID / explicit pads, ``bias`` and
+    ``epilogue`` (a ``conv2d_stream.CONV_EPILOGUES`` kind) applied to
+    the accumulator in the kernel.  Framed as :func:`conv2d_stream`
+    (:func:`_stream_frame`); the row block comes from
+    :func:`repro.core.dse.plan_dwconv_rows`.  Returns float32, or int32
+    accumulators for integer operands."""
+    interpret = _auto_interpret(interpret)
+    kh, kw, c = w.shape
+
+    def plan_rows(hp: int, wp: int) -> int:
+        return plan_dwconv_rows(h=hp, w=wp, c=c, kh=kh, stride=stride,
+                                bytes_per_el=x.dtype.itemsize).blocks["rows"]
+
+    x_p, rows_per_block, h_out, w_out, c_skip = _stream_frame(
+        x, kh, kw, stride, padding, rows_per_block, plan_rows)
+    out = _dw.dwconv2d_stream_pallas(
+        x_p, w, bias, rows_per_block=rows_per_block, w_out=w_out,
+        stride=stride, epilogue=epilogue, interpret=interpret,
     )
     return out[:, c_skip : c_skip + h_out]
 
@@ -235,21 +296,43 @@ def conv2d_same_mm(
 #: (on the VMEM accumulator, before writeback)
 _IN_KERNEL_EPILOGUES = {
     PayloadKind.RELU: "relu",
+    PayloadKind.RELU6: "relu6",
     PayloadKind.SQUARED_RELU: "squared_relu",
 }
 
 
-def _split_conv_epilogue(op):
-    """(in-kernel epilogue string, remaining epilogue entries) for a
-    conv node: a leading unary relu/squared_relu runs on the kernel's
-    accumulator; everything after (constant binops, fused pools) applies
-    to the kernel's output inside the same jit unit."""
+def _split_conv_epilogue(op, env, channels: int):
+    """(bias, in-kernel epilogue string, remaining epilogue entries) for
+    a conv node.  A leading ADD of a rank-1 constant with one value per
+    output channel (the folded bias) runs in the kernel as its bias, a
+    unary relu/relu6/squared_relu after it on the kernel's accumulator;
+    everything after (constant binops, fused pools) applies to the
+    kernel's output inside the same jit unit.  With no such bias the
+    kernel gets none, and is the kernel it was without one."""
     epi = list(op.epilogue)
+    bias = None
+    if epi and epi[0].kind == PayloadKind.ADD and epi[0].operand and (
+        not epi[0].window and env[epi[0].operand].shape == (channels,)
+    ):
+        bias, epi = env[epi[0].operand], epi[1:]
+    kern = None
     if epi and epi[0].operand is None and not epi[0].window and (
         epi[0].kind in _IN_KERNEL_EPILOGUES
     ):
-        return _IN_KERNEL_EPILOGUES[epi[0].kind], epi[1:]
-    return None, epi
+        kern, epi = _IN_KERNEL_EPILOGUES[epi[0].kind], epi[1:]
+    return bias, kern, epi
+
+
+def _tally_conv(kernel: str) -> None:
+    """Count one conv node lowered onto ``kernel`` (``stream`` |
+    ``depthwise`` | ``same_mm``) in the ambient registry's
+    ``lower_conv_total``: once per node each time a group is lowered
+    (traced), so a test or an operator sees where every conv went."""
+    reg = _metrics.current()
+    if reg.enabled:
+        reg.counter("lower_conv_total",
+                    "conv nodes lowered, by the kernel that runs them",
+                    labels=("kernel",)).inc(1, kernel=kernel)
 
 
 def _weight_tile_axes(op, dfg):
@@ -293,7 +376,7 @@ def _weight_tile_axes(op, dfg):
 
 
 def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
-                fast_int_conv: bool = False):
+                fast_int_conv: bool = False, tally: bool = True):
     """Execute one GenericOp with the kernel library (jit-traceable).
 
     ``weight_tiles > 1`` honors the schedule's partial weight streaming:
@@ -316,6 +399,11 @@ def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
     Integer lowerings are bit-exact with each other (modular addition is
     order-independent); float convs always keep the Pallas kernel, so
     batched and per-sample runs stay bit-exact.
+
+    A depthwise conv (:func:`repro.core.analysis.channelwise_dims`) takes
+    :func:`dwconv2d_stream` on every path and for every dtype.  Each conv
+    node bumps ``lower_conv_total`` once (``tally``; a weight-tiled
+    node's tiles count as one).
     """
     if weight_tiles > 1:
         tiled = _weight_tile_axes(op, dfg)
@@ -331,6 +419,7 @@ def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
                         {**env, cname: jax.lax.slice_in_dim(
                             w, t * step, (t + 1) * step, axis=cax)},
                         interpret, fast_int_conv=fast_int_conv,
+                        tally=tally and t == 0,
                     )
                     for t in range(weight_tiles)
                 ]
@@ -341,9 +430,11 @@ def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
         if op.payload == PayloadKind.MAC:
             stream = [i for i in op.inputs if not dfg.values[i].is_constant]
             const = [i for i in op.inputs if dfg.values[i].is_constant]
+            depthwise = bool(channelwise_dims(op))
             if (
                 len(stream) == 1 and len(const) == 1
-                and op.n_dims == 7 and info.dilation == 1
+                and op.n_dims == (6 if depthwise else 7)
+                and info.dilation == 1
             ):
                 x_in, w = env[stream[0]], env[const[0]]
                 # the maps determine the reach; whatever exceeds the
@@ -351,15 +442,30 @@ def _lower_node(op, dfg, env, interpret: bool, weight_tiles: int = 1,
                 # splits end-heavy, VALID reads within bounds -> (0,0))
                 pads = conv_spatial_pads(op, tuple(x_in.shape))
                 padding = (pads[1], pads[2])
+                if depthwise:
+                    if tally:
+                        _tally_conv("depthwise")
+                    bias, kern_epi, rest = _split_conv_epilogue(
+                        op, env, w.shape[-1])
+                    out = dwconv2d_stream(
+                        x_in, w, bias, stride=info.stride, padding=padding,
+                        epilogue=kern_epi, interpret=interpret,
+                    )
+                    return _ref.apply_epilogue(out, rest, env)
                 if not _conv.kernel_accepts(x_in.dtype, w.dtype) or (
                     fast_int_conv and jnp.issubdtype(x_in.dtype, jnp.integer)
                 ):
+                    if tally:
+                        _tally_conv("same_mm")
                     out = conv2d_same_mm(x_in, w,
                                          stride=info.stride, padding=padding)
                     return _ref.apply_epilogue(out, op.epilogue, env)
-                kern_epi, rest = _split_conv_epilogue(op)
+                if tally:
+                    _tally_conv("stream")
+                bias, kern_epi, rest = _split_conv_epilogue(
+                    op, env, w.shape[-1])
                 out = conv2d_stream(
-                    x_in, w,
+                    x_in, w, bias,
                     stride=info.stride, padding=padding,
                     epilogue=kern_epi, interpret=interpret,
                 )

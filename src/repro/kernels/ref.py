@@ -32,7 +32,8 @@ def conv2d(
     ``padding`` is ``"SAME"`` / ``"VALID"`` or an explicit
     ``((top, bottom), (left, right))`` pair sequence (passed straight to
     ``lax.conv_general_dilated`` — the asymmetric-pads import path).
-    ``epilogue`` mirrors the kernel's fused tails (relu / squared_relu)."""
+    ``epilogue`` mirrors the kernel's fused tails (relu / relu6 /
+    squared_relu)."""
     if fuse_relu and epilogue not in (None, "relu"):
         raise ValueError(f"fuse_relu=True conflicts with epilogue={epilogue!r}")
     if jnp.issubdtype(x.dtype, jnp.integer):
@@ -46,14 +47,44 @@ def conv2d(
         padding=padding,
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
-    if fuse_relu or epilogue == "relu":
-        out = jnp.maximum(out, 0)
-    elif epilogue == "squared_relu":
-        r = jnp.maximum(out, 0)
-        out = r * r
-    elif epilogue is not None:
+    return _conv_epilogue(out, "relu" if fuse_relu else epilogue)
+
+
+def _conv_epilogue(out: jax.Array, epilogue: str | None) -> jax.Array:
+    if epilogue is None:
+        return out
+    if epilogue not in ("relu", "relu6", "squared_relu"):
         raise ValueError(f"unsupported conv epilogue {epilogue!r}")
-    return out
+    return unary(epilogue, out)
+
+
+def depthwise_conv2d(
+    x: jax.Array,          # (B, H, W, C)
+    w: jax.Array,          # (KH, KW, C)
+    *,
+    stride: int = 1,
+    padding: str | tuple = "SAME",
+    bias: jax.Array | None = None,
+    epilogue: str | None = None,
+) -> jax.Array:
+    """NHWC depthwise conv: channel ``c`` convolved with ``w[..., c]``
+    alone (``feature_group_count = C``); integers accumulate in int32.
+    ``bias`` (one value per channel) and ``epilogue`` follow the
+    kernel's order: bias first, then the activation."""
+    acc_dtype = jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer) \
+        else jnp.float32
+    c = x.shape[-1]
+    out = lax.conv_general_dilated(
+        x.astype(acc_dtype),
+        w.astype(acc_dtype).reshape(w.shape[:2] + (1, c)),
+        window_strides=(stride, stride),
+        padding=padding,
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=c,
+    )
+    if bias is not None:
+        out = out + bias.astype(acc_dtype)
+    return _conv_epilogue(out, epilogue)
 
 
 # ---------------------------------------------------------------------------
@@ -68,6 +99,8 @@ def conv2d(
 def unary(kind: str, x: jax.Array) -> jax.Array:
     if kind == "relu":
         return jnp.maximum(x, 0)
+    if kind == "relu6":
+        return jnp.clip(x, 0, 6)
     if kind == "squared_relu":
         r = jnp.maximum(x, 0)
         return r * r

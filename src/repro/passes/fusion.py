@@ -20,7 +20,7 @@ Legality (checked per candidate pair producer P → consumer C):
   F2. C reads exactly one non-constant value, exactly once: P's output;
   F3. P's output has no other consumer and is not a graph output;
   F4. C's iteration space equals the shape of P's output value;
-  F5. C's payload is a supported epilogue kind (unary: relu,
+  F5. C's payload is a supported epilogue kind (unary: relu, relu6,
       squared_relu, identity, exp; binary with a constant operand:
       add, mul, max).
 """
@@ -33,6 +33,7 @@ from .base import Pass
 
 FUSIBLE_UNARY = {
     PayloadKind.RELU,
+    PayloadKind.RELU6,
     PayloadKind.SQUARED_RELU,
     PayloadKind.IDENTITY,
     PayloadKind.EXP,

@@ -15,8 +15,9 @@ Supported node shapes (everything ``cnn_graphs`` builds):
 * pure-parallel elementwise ops (identity maps) for every PayloadKind;
 * regular reductions whose map results are all single dims (matmul and
   friends) via einsum built from the indexing maps;
-* NHWC sliding-window MAC (conv2d) via ``ref.conv2d`` (SAME padding —
-  the convention the graph builders use when sizing output values);
+* NHWC sliding-window MAC (conv2d) via ``ref.conv2d``, and its
+  depthwise form (:func:`repro.core.analysis.channelwise_dims`) via
+  ``ref.depthwise_conv2d`` (the padding the maps imply: SAME or VALID);
 * NHWC sliding-window MAX (max pool, non-overlapping or not) via
   ``ref.maxpool2d`` (VALID padding);
 * fused epilogues, including windowed pooling entries.
@@ -33,6 +34,7 @@ import jax.numpy as jnp
 
 from repro.core.analysis import (
     KernelClass,
+    channelwise_dims,
     classify_kernel,
     conv_spatial_pads,
     einsum_spec,
@@ -54,7 +56,9 @@ def _einsum_from_maps(op: GenericOp, operands):
 
 def _conv2d(op: GenericOp, dfg: DFG, env: Mapping[str, jax.Array]):
     info = classify_kernel(op)
-    if op.n_dims != 7 or len(op.inputs) != 2 or info.dilation != 1:
+    depthwise = bool(channelwise_dims(op))
+    if (op.n_dims != (6 if depthwise else 7) or len(op.inputs) != 2
+            or info.dilation != 1):
         raise NotImplementedError(f"{op.name}: unsupported sliding-window shape")
     stream = [i for i in op.inputs if not dfg.values[i].is_constant]
     const = [i for i in op.inputs if dfg.values[i].is_constant]
@@ -62,8 +66,9 @@ def _conv2d(op: GenericOp, dfg: DFG, env: Mapping[str, jax.Array]):
         raise NotImplementedError(f"{op.name}: conv needs 1 stream + 1 const input")
     x = env[stream[0]]
     pads = conv_spatial_pads(op, tuple(x.shape))
-    return ref.conv2d(x, env[const[0]], stride=info.stride,
-                      padding=(pads[1], pads[2]))
+    conv = ref.depthwise_conv2d if depthwise else ref.conv2d
+    return conv(x, env[const[0]], stride=info.stride,
+                padding=(pads[1], pads[2]))
 
 
 def _pool2d(op: GenericOp, env: Mapping[str, jax.Array]):

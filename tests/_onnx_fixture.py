@@ -364,6 +364,92 @@ def resnet_tiny_numpy(x: np.ndarray, w: dict[str, np.ndarray]) -> np.ndarray:
     return h @ w["fc_w"].T.astype(np.int64) + w["fc_b"]
 
 
+def mobilenet_tiny_weights(seed: int = 0) -> dict[str, np.ndarray]:
+    """Float weights of :func:`mobilenet_tiny_model_bytes`."""
+    rng = np.random.default_rng(seed)
+
+    def f32(*shape, scale=0.5):
+        return (rng.standard_normal(shape) * scale).astype(np.float32)
+
+    return {
+        "e_w": f32(16, 8, 1, 1), "e_b": f32(16),
+        "d1_w": f32(16, 1, 3, 3), "d1_b": f32(16),
+        "p_w": f32(8, 16, 1, 1),
+        "d2_w": f32(8, 1, 3, 3), "d2_b": f32(8),
+        "fc_w": f32(5, 8), "fc_b": f32(5),
+        "zero": np.zeros((), np.float32), "six": np.full((), 6, np.float32),
+    }
+
+
+def mobilenet_tiny_model_bytes(seed: int = 0) -> bytes:
+    """An inverted-residual block as PyTorch exports one: 1×1 expansion
+    → Clip(0, 6) → depthwise 3×3 (``group`` = channels, stride 1) →
+    Clip → linear 1×1 projection → Add (the skip), then a stride-2
+    SAME_UPPER depthwise conv → Clip → GlobalAveragePool → Gemm, on a
+    float32 (1, 8, 8, 8) NCHW input.  Clip takes its bounds as scalar
+    initializer inputs (opset 13)."""
+    w = mobilenet_tiny_weights(seed)
+    clip = ["zero", "six"]
+    nodes = [
+        node("Conv", ["input", "e_w", "e_b"], ["e"], "expand",
+             (attr_ints("kernel_shape", [1, 1]),)),
+        node("Clip", ["e"] + clip, ["e6"], "clip_e"),
+        node("Conv", ["e6", "d1_w", "d1_b"], ["d1"], "dw1",
+             (attr_ints("kernel_shape", [3, 3]), attr_int("group", 16),
+              attr_ints("pads", [1, 1, 1, 1]))),
+        node("Clip", ["d1"] + clip, ["d16"], "clip_d1"),
+        node("Conv", ["d16", "p_w"], ["p"], "project",
+             (attr_ints("kernel_shape", [1, 1]),)),
+        node("Add", ["p", "input"], ["res"], "residual"),
+        node("Conv", ["res", "d2_w", "d2_b"], ["d2"], "dw2",
+             (attr_ints("kernel_shape", [3, 3]), attr_int("group", 8),
+              attr_ints("strides", [2, 2]),
+              attr_string("auto_pad", "SAME_UPPER"))),
+        node("Clip", ["d2"] + clip, ["d26"], "clip_d2"),
+        node("GlobalAveragePool", ["d26"], ["gap"], "gap"),
+        node("Flatten", ["gap"], ["flat"], "flatten", (attr_int("axis", 1),)),
+        node("Gemm", ["flat", "fc_w", "fc_b"], ["logits"], "fc",
+             (attr_int("transB", 1),)),
+    ]
+    g = graph(
+        "mobilenet_tiny",
+        nodes,
+        [tensor(k, v) for k, v in w.items()],
+        [value_info("input", (1, 8, 8, 8), FLOAT)],
+        [value_info("logits", (1, 5), FLOAT)],
+    )
+    return model(g)
+
+
+def mobilenet_tiny_numpy(x: np.ndarray, w: dict[str, np.ndarray]) -> np.ndarray:
+    """Float64 NCHW forward pass of :func:`mobilenet_tiny_model_bytes`:
+    depthwise convs as per-channel window sums, written out."""
+    from numpy.lib.stride_tricks import sliding_window_view
+
+    def conv1x1(x, wgt, b=None):
+        out = np.einsum("nchw,oc->nohw", x, wgt[:, :, 0, 0])
+        return out + (0 if b is None else b[None, :, None, None])
+
+    def dwconv(x, wgt, b, stride, pads):  # pads ((t, b), (l, r))
+        (pt, pb), (pl, pr) = pads
+        xp = np.pad(x, ((0, 0), (0, 0), (pt, pb), (pl, pr)))
+        win = sliding_window_view(xp, (3, 3), axis=(2, 3))
+        win = win[:, :, ::stride, ::stride]
+        out = np.einsum("nchwij,cij->nchw", win, wgt[:, 0])
+        return out + b[None, :, None, None]
+
+    relu6 = lambda v: np.clip(v, 0, 6)  # noqa: E731
+    x = x.astype(np.float64)
+    w = {k: v.astype(np.float64) for k, v in w.items()}
+    h = relu6(conv1x1(x, w["e_w"], w["e_b"]))
+    h = relu6(dwconv(h, w["d1_w"], w["d1_b"], 1, ((1, 1), (1, 1))))
+    h = conv1x1(h, w["p_w"]) + x
+    h = relu6(dwconv(h, w["d2_w"], w["d2_b"], 2,
+                     (same4(8, 3, 2), same4(8, 3, 2))))
+    h = h.mean(axis=(2, 3))
+    return h @ w["fc_w"].T + w["fc_b"]
+
+
 if __name__ == "__main__":  # pragma: no cover - fixture regeneration
     import os
 

@@ -14,9 +14,11 @@ except ImportError:
     from _hypothesis_fallback import given, settings, st
 
 from repro.api.builder import (
+    Activation,
     AvgPool,
     Conv2D,
     Dense,
+    DepthwiseConv2D,
     Flatten,
     Graph,
     MaxPool,
@@ -25,6 +27,7 @@ from repro.api.builder import (
     Sequential,
 )
 from repro.core import cnn_graphs
+from repro.core.ir import PayloadKind
 from repro.frontends import (
     ModelCardError,
     ZOO,
@@ -88,16 +91,28 @@ class TestRoundTrip:
 
 class TestRoundTripProperty:
     @given(st.integers(4, 16), st.integers(1, 6), st.integers(1, 3),
-           st.integers(0, 2))
+           st.integers(0, 2), st.integers(0, 3))
     @settings(max_examples=20, deadline=None)
-    def test_random_builder_graphs_round_trip(self, n, c, layers, head):
-        """Random conv cascades with optional pool/residual/dense heads
-        — every one must survive export → import node-for-node."""
+    def test_random_builder_graphs_round_trip(self, n, c, layers, head, dw):
+        """Random conv cascades with optional depthwise/ReLU6 layers
+        (stride 1 or 2, SAME or VALID) and pool/residual/dense heads —
+        every one must survive export → import node-for-node."""
         specs = []
         for _ in range(layers):
             specs += [Conv2D(c), ReLU()]
+            if dw:
+                specs += [DepthwiseConv2D(3, 1, padding="VALID" if dw == 3
+                                          else "SAME"),
+                          Activation(PayloadKind.RELU6)]
+        if dw == 2:
+            specs += [DepthwiseConv2D(3, 2)]
+            n_out = -(-n // 2)
+        else:
+            n_out = n - 2 * layers if dw == 3 else n
+        if n_out < 1:
+            return
         if head == 1:
-            specs += [MaxPool(2) if n % 2 == 0 else ReLU()]
+            specs += [MaxPool(2) if n_out % 2 == 0 else ReLU()]
         elif head == 2:
             specs += [Residual([Conv2D(c), ReLU(), Conv2D(c)]),
                       Flatten(), Dense(4)]

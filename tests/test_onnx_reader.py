@@ -682,3 +682,84 @@ class TestSmallModels:
     def test_import_model_dispatches_onnx(self):
         m = import_model(GOLDEN)
         assert m.source == "onnx"
+
+
+class TestDepthwiseAndClip:
+    """A MobileNetV2-style export: ``Conv`` with ``group`` equal to the
+    channels becomes the depthwise node, ``Clip(0, 6)`` ReLU6."""
+
+    def test_imports_depthwise_nodes_and_relu6(self):
+        from repro.core.analysis import is_depthwise
+        from repro.core.ir import PayloadKind
+
+        m = load_onnx(fx.mobilenet_tiny_model_bytes(0))
+        assert m.missing_params() == []
+        dws = [op for op in m.dfg.nodes if is_depthwise(op)]
+        assert [op.dim_sizes for op in dws] == [(1, 8, 8, 16, 3, 3),
+                                               (1, 4, 4, 8, 3, 3)]
+        assert sum(op.payload == PayloadKind.RELU6
+                   for op in m.dfg.nodes) == 3
+        w = fx.mobilenet_tiny_weights(0)["d1_w"]
+        np.testing.assert_array_equal(
+            m.params[dws[0].inputs[1]][:, :, 5], w[5, 0])
+
+    @pytest.mark.parametrize("seed", [0, 1])
+    def test_matches_numpy_oracle(self, seed):
+        """Float32 through both depthwise strides, the residual add and
+        the head, against the float64 NumPy oracle: 1e-5 relative, the
+        float32 rounding of sums of a few hundred terms."""
+        from repro import api
+
+        data = fx.mobilenet_tiny_model_bytes(seed)
+        m = load_onnx(data)
+        art = api.compile_graph(m.dfg)
+        x = np.random.default_rng(seed + 5).standard_normal(
+            (1, 8, 8, 8)).astype(np.float32)
+        got = np.asarray(art.run({m.dfg.graph_inputs[0]: x}, params=m.params,
+                                 interpret=True), np.float64)
+        want = fx.mobilenet_tiny_numpy(x, fx.mobilenet_tiny_weights(seed))
+        np.testing.assert_allclose(got.reshape(want.shape), want,
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_other_group_counts_still_fail(self):
+        w = np.ones((4, 2, 3, 3), np.int8)   # group 2 over 4 channels
+        g = fx.graph(
+            "g2",
+            [fx.node("Conv", ["x", "w"], ["y"], "conv_g2",
+                     (fx.attr_ints("kernel_shape", [3, 3]),
+                      fx.attr_int("group", 2),
+                      fx.attr_ints("pads", [1, 1, 1, 1])))],
+            [fx.tensor("w", w)],
+            [fx.value_info("x", (1, 4, 8, 8))],
+            [fx.value_info("y", (1, 4, 8, 8))],
+        )
+        with pytest.raises(OnnxImportError, match="conv_g2.*group=2"):
+            load_onnx(fx.model(g))
+
+    @pytest.mark.parametrize("lo,hi", [(0.0, 5.0), (-1.0, 6.0)])
+    def test_other_clip_bounds_fail(self, lo, hi):
+        g = fx.graph(
+            "clip",
+            [fx.node("Clip", ["x", "lo", "hi"], ["y"], "clip_x")],
+            [fx.tensor("lo", np.full((), lo, np.float32)),
+             fx.tensor("hi", np.full((), hi, np.float32))],
+            [fx.value_info("x", (1, 8), fx.FLOAT)],
+            [fx.value_info("y", (1, 8), fx.FLOAT)],
+        )
+        with pytest.raises(OnnxImportError, match="clip_x"):
+            load_onnx(fx.model(g))
+
+    def test_clip_bounds_as_attributes(self):
+        """Opset < 11 spells the bounds as attributes."""
+        from repro.core.ir import PayloadKind
+
+        g = fx.graph(
+            "clip",
+            [fx.node("Clip", ["x"], ["y"], "clip_x",
+                     (fx.attr_float("min", 0.0), fx.attr_float("max", 6.0)))],
+            [],
+            [fx.value_info("x", (1, 8), fx.FLOAT)],
+            [fx.value_info("y", (1, 8), fx.FLOAT)],
+        )
+        m = load_onnx(fx.model(g, opset=10))
+        assert [op.payload for op in m.dfg.nodes] == [PayloadKind.RELU6]

@@ -150,3 +150,41 @@ def test_vmapped_bucket8_float_group(compile_tpu):
     hlo = compile_tpu(
         ops.lower_group(group, interpret=False, jit=False, batch=8), env)
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("h,c,stride", [
+    (112, 32, 1), (112, 96, 2), (28, 192, 2), (14, 576, 1), (7, 960, 1),
+], ids=["112x32", "112x96_s2", "28x192_s2", "14x576", "7x960"])
+def test_mobilenet_v2_depthwise_bucket64(compile_tpu, h, c, stride):
+    """MobileNetV2's depthwise convs at their published widths, with
+    the folded bias and ReLU6, vmapped at the top bucket: the VPU
+    kernel, under its own name."""
+    def fn(x, w, b):
+        return ops.dwconv2d_stream(x, w, b, stride=stride, epilogue="relu6",
+                                   interpret=False)
+
+    hlo = compile_tpu(jax.vmap(fn, in_axes=(0, None, None)),
+                      ((64, 1, h, h, c), jnp.float32),
+                      ((3, 3, c), jnp.float32), ((c,), jnp.float32))
+    assert "tpu_custom_call" in hlo and "ming_dwconv2d_stream" in hlo
+
+
+def _kernel_operands(hlo: str, name: str) -> int:
+    (line,) = [ln for ln in hlo.splitlines()
+               if name in ln.split(" = ")[0] and "tpu_custom_call" in ln]
+    return line.split("custom-call(", 1)[1].split(")", 1)[0].count("%")
+
+
+def test_stream_conv_takes_a_bias_only_where_one_is_fused(compile_tpu):
+    """Without a bias the stream kernel keeps its two operands (the
+    executables of a bias-free model, VGG-16's, are what they were);
+    a fused bias is a third, added in the kernel."""
+    x, w = ((1, 56, 56, 24), jnp.float32), ((1, 1, 24, 144), jnp.float32)
+    plain = compile_tpu(_conv(epilogue="relu"), x, w)
+    assert _kernel_operands(plain, "ming_conv2d_stream") == 2
+
+    def biased(x, w, b):
+        return ops.conv2d_stream(x, w, b, epilogue="relu6", interpret=False)
+
+    hlo = compile_tpu(biased, x, w, ((144,), jnp.float32))
+    assert _kernel_operands(hlo, "ming_conv2d_stream") == 3
